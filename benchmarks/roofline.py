@@ -14,6 +14,7 @@ from repro.runtime.roofline import (  # noqa: F401  (re-exported surface)
     KINDS,
     MACHINE_BALANCE,
     PEAK_FLOPS,
+    REFERENCE_KIND,
     SIZES,
     arithmetic_intensity,
     iteration_profile,
@@ -34,7 +35,7 @@ def run(full: bool = False) -> None:
         "# roofline: name,us_per_call,m,n,kind,tile_b,flops_per_iter,"
         "bytes_per_iter,intensity,roofline_frac"
     )
-    print(f"# machine balance (v5e-class): {MACHINE_BALANCE:.0f} flop/byte")
+    print(f"# machine balance ({REFERENCE_KIND}, bf16 peak): {MACHINE_BALANCE:.0f} flop/byte")
     for size in SIZES:
         for kind in KINDS:
             tile = 1

@@ -9,6 +9,8 @@ from __future__ import annotations
 import argparse
 import traceback
 
+from repro.runtime import compile_cache
+
 from . import (
     fig5_layout,
     fig6_transfer,
@@ -55,6 +57,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true", help="paper-scale sizes")
     ap.add_argument("--only", default=None, help="comma-separated subset")
     args = ap.parse_args()
+    compile_cache.enable()
     names = list(BENCHES) if not args.only else args.only.split(",")
     failures = []
     for name in names:

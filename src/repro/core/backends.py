@@ -856,10 +856,13 @@ def reset_warnings() -> None:
 
 
 #: Fault-recovery routing: the backend a faulted dispatch round retries
-#: on.  Only BIT-IDENTICAL twins appear — the pallas kernels and the xla
-#: drivers run the same ``core/engine.py`` / ``core/revised.py`` blocks
-#: and their resume states are interchangeable, so a retry on the twin
-#: continues the carried state exactly.  Backends with no twin (``xla``,
+#: on.  Only twins appear — the pallas kernels and the xla drivers run
+#: the same ``core/engine.py`` / ``core/revised.py`` blocks and their
+#: resume states are interchangeable, so a retry on the twin continues
+#: the carried state.  On CPU the twins are bit-identical; on a TPU v5e
+#: only single-phase tableau solves are, because Mosaic and XLA round
+#: the phase-II objective and pricing matmuls differently (``PERF.md``).
+#: Backends with no twin (``xla``,
 #: ``pdhg``, ``reference``) retry in place: a different-tolerance
 #: substitute would silently change answers, which a fault must never do.
 FAULT_FALLBACKS = {"pallas": "xla", "pallas-shared": "xla-shared"}
@@ -868,7 +871,7 @@ FAULT_FALLBACKS = {"pallas": "xla", "pallas-shared": "xla-shared"}
 def fault_fallback(name: str) -> str:
     """The backend name a faulted round of ``name`` should retry on.
 
-    Returns ``name`` itself when no bit-identical twin exists (see
+    Returns ``name`` itself when no twin exists (see
     :data:`FAULT_FALLBACKS`); warns once per rerouted backend through
     the same warn-once table as the VMEM fallbacks.
     """
@@ -877,8 +880,7 @@ def fault_fallback(name: str) -> str:
         _warn_once(
             ("fault-fallback", name),
             f"{name} backend: dispatch fault — retrying the round from "
-            f"its carried resume state on the {target} backend "
-            "(bit-identical twin)",
+            f"its carried resume state on the {target} backend (its twin)",
         )
     return target
 
@@ -888,10 +890,9 @@ def _pallas_vmem_fallback(
 ) -> Optional[str]:
     """The backend name this shape must route to, or None to run the kernel.
 
-    A shape whose SINGLE-LP tableau exceeds the kernel's VMEM budget
-    cannot run as a Pallas tile at any ``tile_b`` — historically those
-    shapes just failed inside Mosaic, then fell back to a hard-coded
-    ``xla``.  The fallback now consults the shape-routing table
+    A shape whose smallest legal tile (8 LPs, ``kernels/ops.py:MIN_TILE_B``)
+    exceeds the kernel's VMEM budget cannot run as a Pallas tile — it
+    would fail inside Mosaic.  The fallback consults the shape-routing table
     (:func:`route_shape`): below the routing frontier the substitute is
     ``xla`` (bit-identical results — both simplex backends drive the
     same ``core/engine.py`` blocks, and their resume states are
@@ -926,9 +927,10 @@ def _pallas_vmem_fallback(
     dtype_str = str(jnp.dtype(dtype))
     _warn_once(
         ("pallas-vmem", m, n, dtype_str, layout),
-        f"pallas backend: single-LP tableau for shape (m={m}, n={n}, "
+        f"pallas backend: tableau for shape (m={m}, n={n}, "
         f"{dtype_str}, layout={layout!r}) needs {per_lp} VMEM bytes/LP "
-        f"against the {budget}-byte per-tile budget "
+        f"against the {budget}-byte per-tile budget, which must hold "
+        f"{kernel_ops.MIN_TILE_B} LPs "
         f"({kernel_ops.VMEM_BUDGET_BYTES} total x "
         f"{kernel_ops.VMEM_TILE_FRACTION} tile fraction); routing to the "
         f"{target} backend ({fidelity})",

@@ -191,10 +191,16 @@ def _stage(arr: jnp.ndarray, mesh, axes) -> jnp.ndarray:
 
 def _stage_batch(batch, lo: int, hi: int, mesh, axes):
     if isinstance(batch, SharedLPBatch):
-        # The shared A has no batch dimension — staged whole (replicated,
-        # not sharded) while the per-LP c/b rows slice and shard as usual.
+        # The shared A has no batch dimension — staged whole (replicated
+        # on every device of the mesh, not sharded) while the per-LP c/b
+        # rows slice and shard as usual.
+        whole = (
+            jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+            if mesh and axes
+            else None
+        )
         return SharedLPBatch(
-            jax.device_put(batch.a),
+            jax.device_put(batch.a, whole),
             _stage(batch.b[lo:hi], mesh, axes),
             _stage(batch.c[lo:hi], mesh, axes),
             None
@@ -570,8 +576,9 @@ def dispatch_round_safe(
     in place — with capped exponential backoff
     (``options.retry_backoff``, ceiling :data:`RETRY_BACKOFF_CAP`).
     After ``options.retry_budget`` failed retries, or on a non-transient
-    error (:data:`repro.runtime.chaos.NON_TRANSIENT`), the exception
-    propagates.
+    error (:func:`repro.runtime.chaos.is_transient`: bad arguments, and
+    kernel lowering or compile failures, which no retry or twin may
+    hide), the exception propagates.
 
     The clean path is one ``try`` — no extra dispatches, no syncs.
     Note ``SolveStats`` counters recorded by an aborted attempt's

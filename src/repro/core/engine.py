@@ -9,8 +9,9 @@ drivers over the building blocks here; only the NumPy oracle
 Every function is pure ``jax.numpy`` over batched tableaus and is
 formulated with ``broadcasted_iota`` + masked reductions — no scatters
 or 1-D iota — so the SAME code lowers cleanly both through XLA and
-through Mosaic inside a Pallas kernel body.  The only single-element
-extractions (pivot column, pivot row, basic costs) go through helpers
+through Mosaic inside a Pallas kernel body.  The pivot column is taken
+in one-hot form by both drivers (:func:`take_col`); the other
+single-element extractions (pivot row, basic costs) go through helpers
 taking a static ``gather`` flag: ``gather=True`` uses
 ``take_along_axis`` (cheap under XLA — the XLA driver's choice),
 ``gather=False`` a one-hot multiply-reduction (the only form Mosaic
@@ -43,7 +44,7 @@ Pivot rules
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,12 +80,17 @@ def phase1_feasibility_tol(b: jnp.ndarray) -> jnp.ndarray:
 
 
 def column_ids(q: int) -> jnp.ndarray:
-    """(1, q) int32 column indices (2-D iota — the Mosaic-safe form)."""
-    return jax.lax.broadcasted_iota(jnp.int32, (1, q), 1)
+    """(1, 1, q) int32 column indices (a lane iota — the Mosaic-safe form)."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, 1, q), 2)
+
+
+def row_ids(r: int) -> jnp.ndarray:
+    """(1, r, 1) int32 row indices (a sublane iota)."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, r, 1), 1)
 
 
 def eligible_mask(q_total: int, m: int, n: int) -> jnp.ndarray:
-    """(1, q_total) bool — columns allowed to enter the basis.
+    """(1, 1, q_total) bool — columns allowed to enter the basis.
 
     Column 0 (the RHS), the artificial block (dense layout), and any lane
     padding beyond the true ``q`` are never eligible; only originals and
@@ -110,58 +116,114 @@ def _mix32(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
-def rpc_noise(seed, step, row_offset, bsz: int, q: int, dtype) -> jnp.ndarray:
-    """(bsz, q) uniform noise in ``dtype`` for the RPC rule, counter-based.
+def rpc_noise(
+    seed, step, row_offset, bsz: int, q: int, dtype, col_offset: int = 0
+) -> jnp.ndarray:
+    """(bsz, 1, q) uniform noise in ``dtype`` for the RPC rule, counter-based.
 
     Keyed on (seed, iteration step, global LP row, column) so the draw is
     stateless — no PRNG key threading — and identical regardless of how
     the batch is tiled (``row_offset`` is the driver's global row base,
-    e.g. ``program_id * tile_b`` in the Pallas kernel).  Pure uint32
+    e.g. ``program_id * tile_b`` in the Pallas kernel); ``col_offset``
+    shifts the column key, so a segment of a row draws exactly the slice
+    of the whole row's noise (:func:`select_entering_segments`).  Pure uint32
     shift/xor/multiply arithmetic, which lowers under both XLA and
-    Mosaic; the float conversion happens in the objective-row ``dtype``
-    (fixing the old float32-only Gumbel draw).
+    Mosaic; the float conversion happens in the objective-row ``dtype``.
     """
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bsz, q), 0).astype(jnp.uint32)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bsz, q), 1).astype(jnp.uint32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bsz, 1, q), 0).astype(jnp.uint32)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bsz, 1, q), 2) + col_offset
+    cols = cols.astype(jnp.uint32)
     rows = rows + jnp.asarray(row_offset).astype(jnp.uint32)
     key = jnp.asarray(seed).astype(jnp.uint32) * jnp.uint32(0x9E3779B9)
     ctr = jnp.asarray(step).astype(jnp.uint32) * jnp.uint32(0x85EBCA6B)
     x = _mix32(rows * jnp.uint32(0xC2B2AE35) ^ cols ^ key ^ ctr)
-    # Top 24 bits -> uniform in [0, 1); exact in float32 and float64.
-    return (x >> jnp.uint32(8)).astype(dtype) * jnp.asarray(1.0 / (1 << 24), dtype)
+    # Top 24 bits -> uniform in [0, 1); exact in float32 and float64.  The
+    # shifted value fits int32, whose float conversion Mosaic lowers.
+    top = (x >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(dtype) * jnp.asarray(1.0 / (1 << 24), dtype)
 
 
 # ---------------------------------------------------------------------------
-# single-element extraction: gather (XLA) vs one-hot reduce (Mosaic)
+# layout conversions and single-element extraction
 # ---------------------------------------------------------------------------
 #
-# Both forms produce bit-identical values (a one-hot sum has exactly one
-# non-zero term); the flag only selects the formulation the target
-# compiler handles well.  ``gather`` must be static.
+# Every per-LP quantity keeps the rank of the tableau it came from, so
+# that Mosaic never has to move an axis between lanes and sublanes:
+#
+#   per-LP scalar    (B, 1, 1)   e.g. the entering column, status, phase
+#   row vector       (B, 1, Q)   indexed by tableau column (objective row)
+#   column vector    (B, R, 1)   indexed by tableau row (basis, pivot column)
+#
+# The ``gather`` flag (static) picks the formulation the target compiler
+# handles well: ``True`` uses ``take_along_axis`` and reshapes (cheap
+# under XLA — the XLA driver's choice), ``False`` one-hot selects and
+# reductions (the only forms Mosaic lowers — the Pallas kernels'
+# choice).  Both extract the SAME value exactly (a one-hot sum has a
+# single non-zero term), so the drivers agree bit-for-bit either way.
 
 
-def take_col(mat: jnp.ndarray, j: jnp.ndarray, gather: bool) -> jnp.ndarray:
-    """Column ``j`` per batch element: (B, R, Q), (B,) -> (B, R)."""
-    if gather:
-        return jnp.take_along_axis(mat, j[:, None, None], axis=-1)[..., 0]
-    oh = column_ids(mat.shape[-1]) == j[:, None]
-    return jnp.sum(jnp.where(oh[:, None, :], mat, 0.0), axis=-1)
+def widen_rows(flag: jnp.ndarray, r: int) -> jnp.ndarray:
+    """Per-LP flag (B, 1, 1) -> (B, r, 1), for masks that later meet lanes.
+
+    Mosaic broadcasts a (B, 1, 1) value along sublanes, or a (B, r, 1)
+    column along lanes, but not a (B, 1, 1) value along both at once —
+    so a per-LP mask over a (B, r, Q) block is widened in two steps.
+    """
+    return flag & (row_ids(r) >= 0)
+
+
+def first_index(mask: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Index of the first True along ``axis`` (keepdims), 0 if none.
+
+    The ``argmax``-of-bool convention, written as a min over an iota so
+    it lowers identically under XLA and Mosaic.
+    """
+    axis = axis % mask.ndim
+    size = mask.shape[axis]
+    shape = [1] * mask.ndim
+    shape[axis] = size
+    ids = jax.lax.broadcasted_iota(jnp.int32, tuple(shape), axis)
+    idx = jnp.min(jnp.where(mask, ids, size), axis=axis, keepdims=True)
+    return jnp.where(idx == size, 0, idx)
+
+
+def take_col(mat: jnp.ndarray, j: jnp.ndarray) -> jnp.ndarray:
+    """Column ``j`` per batch element: (B, R, Q), (B, 1, 1) -> (B, R, 1).
+
+    One-hot under both drivers: XLA on a TPU v5e miscompiles the gather
+    form (``take_along_axis`` along the lanes) at some batch sizes — every
+    row of a 2,500-LP m = n = 100 batch came back wrong, while 2,048,
+    4,096, 5,000 and 10,000 LPs were right.
+    """
+    oh = column_ids(mat.shape[2]) == j
+    return jnp.sum(jnp.where(oh, mat, 0.0), axis=2, keepdims=True)
 
 
 def take_row(mat: jnp.ndarray, i: jnp.ndarray, gather: bool) -> jnp.ndarray:
-    """Row ``i`` per batch element: (B, R, Q), (B,) -> (B, Q)."""
+    """Row ``i`` per batch element: (B, R, Q), (B, 1, 1) -> (B, 1, Q)."""
     if gather:
-        return jnp.take_along_axis(mat, i[:, None, None], axis=1)[:, 0, :]
-    oh = jax.lax.broadcasted_iota(jnp.int32, (1, mat.shape[1]), 1) == i[:, None]
-    return jnp.sum(jnp.where(oh[:, :, None], mat, 0.0), axis=1)
+        return jnp.take_along_axis(mat, i, axis=1)
+    oh = row_ids(mat.shape[1]) == i
+    return jnp.sum(jnp.where(oh, mat, 0.0), axis=1, keepdims=True)
 
 
-def take_elem(vec: jnp.ndarray, i: jnp.ndarray, gather: bool) -> jnp.ndarray:
-    """Element ``i`` per batch element: (B, K), (B,) -> (B,)."""
+def to_column(vec: jnp.ndarray, gather: bool) -> jnp.ndarray:
+    """Row vector to column vector: (B, 1, K) -> (B, K, 1)."""
     if gather:
-        return jnp.take_along_axis(vec, i[:, None], axis=-1)[:, 0]
-    oh = jax.lax.broadcasted_iota(jnp.int32, (1, vec.shape[1]), 1) == i[:, None]
-    return jnp.sum(jnp.where(oh, vec, 0.0), axis=-1)
+        return jnp.swapaxes(vec, 1, 2)
+    k = vec.shape[2]
+    eye = row_ids(k) == column_ids(k)
+    return jnp.sum(jnp.where(eye, vec, 0), axis=2, keepdims=True)
+
+
+def to_row(vec: jnp.ndarray, k_out: int, gather: bool) -> jnp.ndarray:
+    """Column vector to a zero-padded row: (B, K, 1) -> (B, 1, k_out)."""
+    k = vec.shape[1]
+    if gather:
+        row = jnp.swapaxes(vec, 1, 2)
+        return jnp.pad(row, ((0, 0), (0, 0), (0, k_out - k)))
+    eye = row_ids(k) == column_ids(k_out)
+    return jnp.sum(jnp.where(eye, vec, 0), axis=1, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
@@ -180,35 +242,77 @@ def select_entering(
 
     Parameters
     ----------
-    obj : (B, Q) objective row (reduced costs).
-    elig : (1, Q) or (B, Q) bool eligibility mask (:func:`eligible_mask`).
+    obj : (B, 1, Q) objective row (reduced costs).
+    elig : (1, 1, Q) or (B, 1, Q) bool eligibility mask
+        (:func:`eligible_mask`).
     rule : ``"lpc"`` | ``"rpc"`` | ``"bland"`` (static).
     tol : reduced-cost tolerance (static).
-    noise : (B, Q) uniform noise, required for ``"rpc"`` only
+    noise : (B, 1, Q) uniform noise, required for ``"rpc"`` only
         (:func:`rpc_noise`).
 
     Returns
     -------
-    e : (B,) int32 entering column index.
-    max_c : (B,) the LARGEST eligible reduced cost (not necessarily at
-        ``e`` for rpc/bland) — the optimality certificate:
+    e : (B, 1, 1) int32 entering column index.
+    max_c : (B, 1, 1) the LARGEST eligible reduced cost (not necessarily
+        at ``e`` for rpc/bland) — the optimality certificate:
         ``max_c <= tol`` means no improving column exists under ANY rule.
     """
     cand = jnp.where(elig, obj, -BIG)
-    max_c = jnp.max(cand, axis=-1)
+    max_c = jnp.max(cand, axis=-1, keepdims=True)
     if rule == LPC:
-        e = jnp.argmax(cand, axis=-1).astype(jnp.int32)
+        e = first_index(cand == max_c, axis=-1)
     elif rule == BLAND:
-        pos = elig & (obj > tol)
-        # argmax over bool returns the FIRST True -> smallest-index rule.
-        e = jnp.argmax(pos, axis=-1).astype(jnp.int32)
+        e = first_index(elig & (obj > tol), axis=-1)
     elif rule == RPC:
         if noise is None:
             raise ValueError("rpc rule needs a noise array (engine.rpc_noise)")
         pos = elig & (obj > tol)
-        e = jnp.argmax(jnp.where(pos, noise, -BIG), axis=-1).astype(jnp.int32)
+        score = jnp.where(pos, noise, -BIG)
+        e = first_index(score == jnp.max(score, axis=-1, keepdims=True), axis=-1)
     else:
         raise ValueError(f"unknown pivot rule {rule!r}; expected one of {RULES}")
+    return e, max_c
+
+
+def select_entering_segments(
+    rows: Sequence[jnp.ndarray],
+    starts: Sequence[int],
+    rule: str,
+    tol: float,
+    noises: Optional[Sequence[jnp.ndarray]] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`select_entering` over a row held as separate segments.
+
+    ``rows[k]`` (B, 1, K_k) holds columns ``starts[k] ..`` of the
+    objective row, every one of them eligible; columns in no segment are
+    not.  Returns the same ``(e, max_c)`` as :func:`select_entering` on
+    the assembled row whenever an improving column exists (ties go to
+    the lowest column, as there) — without assembling it, since Mosaic
+    lays a lane concatenation out poorly.  ``noises`` are the matching
+    segments of the RPC noise (``rpc_noise(..., col_offset=start)``).
+    """
+    if rule not in RULES:
+        raise ValueError(f"unknown pivot rule {rule!r}; expected one of {RULES}")
+    if rule == RPC and noises is None:
+        raise ValueError("rpc rule needs noise arrays (engine.rpc_noise)")
+    max_c = best = e = None
+    for k, (row, start) in enumerate(zip(rows, starts)):
+        if rule == LPC:
+            score = row
+        elif rule == BLAND:
+            score = jnp.where(row > tol, 1.0, 0.0).astype(row.dtype)
+        else:
+            score = jnp.where(row > tol, noises[k], -BIG)
+        top = jnp.max(score, axis=-1, keepdims=True)
+        idx = start + first_index(score == top, axis=-1)
+        seg_max = jnp.max(row, axis=-1, keepdims=True)
+        if e is None:
+            max_c, best, e = seg_max, top, idx
+        else:
+            take = top > best  # strict: earlier segments win ties
+            max_c = jnp.maximum(max_c, seg_max)
+            best = jnp.where(take, top, best)
+            e = jnp.where(take, idx, e)
     return e, max_c
 
 
@@ -221,11 +325,12 @@ def phase2_objective(
 ) -> jnp.ndarray:
     """The phase-II objective row for the current basis: ``c_ext - c_B . rows``.
 
-    ``c_ext``: (B, Q) phase-II costs (zeros except columns 1..n).  Column
-    0 of the result holds ``-c_B . b = -z0`` (the ``-z0`` convention).
-    The pricing contraction is a ``dot_general`` with
-    ``preferred_element_type`` pinned to the tableau dtype so XLA and
-    Mosaic accumulate identically.
+    ``basis``: (B, m, 1) column vector; ``c_ext``: (B, 1, Q) phase-II
+    costs (zeros except columns 1..n).  Returns (B, 1, Q); column 0 holds
+    ``-c_B . b = -z0`` (the ``-z0`` convention).  The pricing
+    contraction is a batched ``dot_general`` at ``HIGHEST`` precision:
+    on a TPU both XLA and Mosaic would otherwise contract float32 in a
+    single bfloat16 pass, far too coarse for a pivoting tolerance.
 
     Layout note: a still-basic (degenerate) artificial appears as a basis
     ID ``>= spec.art_start``.  Its phase-II cost is 0 under either layout
@@ -237,19 +342,17 @@ def phase2_objective(
     m = spec.m
     if gather:
         qe = c_ext.shape[-1]
-        cb = jnp.take_along_axis(
-            c_ext, jnp.minimum(basis, qe - 1), axis=-1
-        )  # (B, m)
+        cb = jnp.take_along_axis(c_ext, jnp.minimum(basis, qe - 1), axis=2)
     else:
-        qp = tab.shape[-1]
-        basis_oh = basis[:, :, None] == column_ids(qp)[None, :, :]  # (B, m, Q)
-        cb = jnp.sum(jnp.where(basis_oh, c_ext[:, None, :], 0.0), axis=-1)
+        hit = basis == column_ids(c_ext.shape[-1])  # (B, m, Q)
+        cb = jnp.sum(jnp.where(hit, c_ext, 0.0), axis=2, keepdims=True)
     priced = jax.lax.dot_general(
-        cb[:, None, :],
+        cb,
         tab[:, :m, :],
-        (((2,), (1,)), ((0,), (0,))),
+        (((1,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=tab.dtype,
-    )[:, 0, :]  # (B, Q)
+    )  # (B, 1, Q)
     return c_ext - priced
 
 
@@ -275,17 +378,19 @@ def phase_transition(
     reads ``-z0`` from the objective row — never the artificial columns,
     which is why the compact layout can drop them.
 
+    ``phase``, ``status``, ``at_opt`` and ``feas_tol`` are (B, 1, 1).
     Returns the updated ``(tab, phase, status)``.
     """
     m = spec.m
     active = status == RUNNING
     p1_done = active & at_opt & (phase == 1)
-    feasible = tab[:, m, 0] <= feas_tol
+    feasible = tab[:, m : m + 1, 0:1] <= feas_tol
     to_phase2 = p1_done & feasible
     status = jnp.where(p1_done & ~feasible, INFEASIBLE, status)
     status = jnp.where(active & at_opt & (phase == 2), OPTIMAL, status)
     new_obj = phase2_objective(tab, basis, spec, c_ext, gather)
-    tab = tab.at[:, m, :].set(jnp.where(to_phase2[:, None], new_obj, tab[:, m, :]))
+    rewrite = to_phase2 & (row_ids(tab.shape[1]) == m)  # (B, R, 1)
+    tab = jnp.where(rewrite, new_obj, tab)
     phase = jnp.where(to_phase2, 2, phase)
     return tab, phase, status
 
@@ -296,7 +401,6 @@ def ratio_test(
     e: jnp.ndarray,
     spec: TableauSpec,
     tol: float,
-    gather: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Min-ratio leaving-row selection, branch-free (the INT_MAX trick).
 
@@ -315,20 +419,20 @@ def ratio_test(
 
     Returns
     -------
-    l : (B,) int32 leaving row.
-    min_ratio : (B,) the winning ratio (``>= BIG/2`` <=> unbounded).
-    full_col : (B, M1) the full entering column incl. the objective row —
-        reused by :func:`pivot_update`.
+    l : (B, 1, 1) int32 leaving row.
+    min_ratio : (B, 1, 1) the winning ratio (``>= BIG/2`` <=> unbounded).
+    full_col : (B, R, 1) the full entering column incl. the objective row
+        — reused by :func:`pivot_update`.
     """
     m = spec.m
-    full_col = take_col(tab, e, gather)  # (B, M1)
-    col = full_col[:, :m]
-    rhs = tab[:, :m, 0]
+    full_col = take_col(tab, e)  # (B, R, 1)
+    col = full_col[:, :m, :]
+    rhs = tab[:, :m, 0:1]
     ratios = jnp.where(col > tol, rhs / jnp.where(col > tol, col, 1.0), BIG)
     zero_art = (basis >= spec.art_start) & (rhs <= tol) & (col < -tol)
     ratios = jnp.where(zero_art, 0.0, ratios)
-    l = jnp.argmin(ratios, axis=-1).astype(jnp.int32)
-    min_ratio = jnp.min(ratios, axis=-1)
+    min_ratio = jnp.min(ratios, axis=1, keepdims=True)
+    l = first_index(ratios == min_ratio, axis=1)
     return l, min_ratio, full_col
 
 
@@ -355,19 +459,14 @@ def pivot_update(
     the layout stores — this is where the compact layout saves its ~33%
     of rank-1 flops on square LPs.
     """
-    m = spec.m
-    m1p = tab.shape[1]
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
-    l_oh_rows = row_ids == l[:, None]  # (B, m)
-    pr = take_row(tab[:, :m, :], l, gather)  # (B, Q)
-    pe = take_elem(full_col[:, :m], l, gather)  # (B,)
-    npr = pr / jnp.where(jnp.abs(pe) > tol, pe, 1.0)[:, None]
-    updated = tab - full_col[:, :, None] * npr[:, None, :]
-    row_ids_full = jax.lax.broadcasted_iota(jnp.int32, (1, m1p), 1)
-    l_row_sel = (row_ids_full == l[:, None])[:, :, None]  # (B, M1, 1)
-    updated = jnp.where(l_row_sel, npr[:, None, :], updated)
-    tab = jnp.where(do_pivot[:, None, None], updated, tab)
-    basis = jnp.where(do_pivot[:, None] & l_oh_rows, e[:, None], basis)
+    l_rows = row_ids(tab.shape[1]) == l  # (B, R, 1); l < m always
+    pr = take_row(tab, l, gather)  # (B, 1, Q)
+    pe = take_row(full_col, l, gather)  # (B, 1, 1)
+    npr = pr / jnp.where(jnp.abs(pe) > tol, pe, 1.0)
+    updated = tab - full_col * npr
+    updated = jnp.where(l_rows, npr, updated)
+    tab = jnp.where(widen_rows(do_pivot, tab.shape[1]), updated, tab)
+    basis = jnp.where(do_pivot & (row_ids(spec.m) == l), e, basis)
     return tab, basis
 
 
@@ -383,16 +482,15 @@ def extract_solution(
 
     ``objective = -tab[:, m, 0]`` where OPTIMAL, else ``fill`` (the XLA
     driver uses ``-inf``; the Pallas kernel uses a finite sentinel and
-    re-masks outside).  ``x``: (B, n_out) one-hot scatter of the RHS into
-    the original-variable slots (basis column ``j+1`` <-> ``x_j``);
-    non-optimal LPs report 0.  Reads only the RHS column and the basis —
-    layout-independent by construction.
+    re-masks outside), as (B, 1, 1).  ``x``: (B, 1, n_out) one-hot
+    scatter of the RHS into the original-variable slots (basis column
+    ``j+1`` <-> ``x_j``); non-optimal LPs report 0.  Reads only the RHS
+    column and the basis — layout-independent by construction.
     """
     m = spec.m
-    objective = jnp.where(status == OPTIMAL, -tab[:, m, 0], fill)
-    rhs = tab[:, :m, 0]  # (B, m)
-    var_ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_out), 2)
-    hit = basis[:, :, None] == var_ids + 1
-    x = jnp.sum(jnp.where(hit, rhs[:, :, None], 0.0), axis=1)  # (B, n_out)
-    x = jnp.where((status == OPTIMAL)[:, None], x, 0.0)
+    opt = status == OPTIMAL
+    objective = jnp.where(opt, -tab[:, m : m + 1, 0:1], fill)
+    rhs = tab[:, :m, 0:1]  # (B, m, 1)
+    hit = (basis == column_ids(n_out) + 1) & widen_rows(opt, m)  # (B, m, n_out)
+    x = jnp.sum(jnp.where(hit, rhs, 0.0), axis=1, keepdims=True)
     return objective, x

@@ -228,13 +228,17 @@ def init_state(bsz: int, m: int, n: int, dtype) -> PDHGResumeState:
 
 
 def matvec(a: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
-    """Batched ``A @ x``: (B, m, n), (B, n) -> (B, m) via dot_general."""
-    return jnp.einsum("bmn,bn->bm", a, x)
+    """Batched ``A @ x``: (B, m, n), (B, n) -> (B, m) via dot_general.
+
+    ``HIGHEST`` precision: XLA's default on TPU would contract float32 in
+    a single bfloat16 pass, far too coarse for a 1e-4 KKT tolerance.
+    """
+    return jnp.einsum("bmn,bn->bm", a, x, precision=jax.lax.Precision.HIGHEST)
 
 
 def rmatvec(a: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
-    """Batched ``A' @ y``: (B, m, n), (B, m) -> (B, n) via dot_general."""
-    return jnp.einsum("bmn,bm->bn", a, y)
+    """Batched ``A' @ y``: (B, m, n), (B, m) -> (B, n) (``HIGHEST`` precision)."""
+    return jnp.einsum("bmn,bm->bn", a, y, precision=jax.lax.Precision.HIGHEST)
 
 
 def _l2(v: jnp.ndarray) -> jnp.ndarray:
@@ -293,6 +297,29 @@ def step_sizes(
 # ---------------------------------------------------------------------------
 
 
+def _lp_axes(v: jnp.ndarray) -> Tuple[int, ...]:
+    """The non-batch axes of ``v`` that are longer than 1.
+
+    Reducing only those keeps each Mosaic reduction a single-axis one
+    (rows reduce over lanes, columns over sublanes).
+    """
+    return tuple(i for i in range(1, v.ndim) if v.shape[i] != 1)
+
+
+def _lp_sum(v: jnp.ndarray) -> jnp.ndarray:
+    """Per-LP sum over every non-batch axis, keeping the rank."""
+    return jnp.sum(v, axis=_lp_axes(v), keepdims=True)
+
+
+def _lp_max(v: jnp.ndarray) -> jnp.ndarray:
+    """Per-LP max over every non-batch axis, keeping the rank."""
+    return jnp.max(v, axis=_lp_axes(v), keepdims=True)
+
+
+def _lp_norm(v: jnp.ndarray) -> jnp.ndarray:
+    return jnp.sqrt(_lp_sum(v * v))
+
+
 def pdhg_step(
     a: jnp.ndarray,
     b: jnp.ndarray,
@@ -327,6 +354,12 @@ def pdhg_step(
     everywhere, so converged/certified LPs coast (lockstep) without
     their results drifting.
 
+    Rank-agnostic: per-LP scalars (``inner``, the growth norms,
+    ``status``, ``iters``, ``tau``, ``sigma``, ``scales``) keep the rank
+    of the vectors — (B, 1) against (B, n) vectors in the XLA driver,
+    (B, 1, 1) against (B, 1, n) rows and (B, m, 1) columns in the Pallas
+    kernel — so every broadcast is a plain one and no axis ever moves.
+
     Everything here is per-LP arithmetic — no cross-LP reduction — which
     is the property the compaction bit-stability contract rests on.
     """
@@ -335,10 +368,10 @@ def pdhg_step(
     aty = rmv(a, y)
 
     # --- (1) termination: relative KKT residuals on (x, y) -----------------
-    pres = _l2(jnp.maximum(ax - b, 0.0)) / bscale
-    dres = _l2(jnp.maximum(c - aty, 0.0)) / cscale
-    pobj = jnp.sum(c * x, axis=-1)
-    dobj = jnp.sum(b * y, axis=-1)
+    pres = _lp_norm(jnp.maximum(ax - b, 0.0)) / bscale
+    dres = _lp_norm(jnp.maximum(c - aty, 0.0)) / cscale
+    pobj = _lp_sum(c * x)
+    dobj = _lp_sum(b * y)
     gap = jnp.abs(pobj - dobj) / (1.0 + jnp.abs(pobj) + jnp.abs(dobj))
     opt = (pres <= tol) & (dres <= tol) & (gap <= tol)
 
@@ -349,13 +382,13 @@ def pdhg_step(
     # feasible iterate has relu(Ax - b) = 0 exactly), but only the ray
     # keeps GROWING by ~restart * step * (c . d) per period — a bounded
     # iterate plateaus at ||x*|| and fails the growth test.
-    xnorm = _l2(x)
-    ynorm = _l2(y)
+    xnorm = _lp_norm(x)
+    ynorm = _lp_norm(y)
     at_period = inner + 1 >= restart
     ray_eps = CERT_EPS * jnp.maximum(anorm, 1.0)
     # Primal infeasibility: y/||y|| with A'y >= 0 (up to ray_eps) and
     # b.y < 0 — the dual ray a primal-infeasible LP drives to infinity.
-    dual_ray = jnp.max(jnp.maximum(-aty, 0.0), axis=-1) / jnp.maximum(ynorm, _TINY)
+    dual_ray = _lp_max(jnp.maximum(-aty, 0.0)) / jnp.maximum(ynorm, _TINY)
     infeas = (
         at_period
         & (ynorm >= DIVERGENCE_GUARD)
@@ -366,7 +399,7 @@ def pdhg_step(
     # Unboundedness: x/||x|| with Ax <= 0 and c.x > 0, AND a near-feasible
     # trajectory (small pres) — an infeasible LP can also blow up its
     # primal block, but never with a small primal residual.
-    prim_ray = jnp.max(jnp.maximum(ax, 0.0), axis=-1) / jnp.maximum(xnorm, _TINY)
+    prim_ray = _lp_max(jnp.maximum(ax, 0.0)) / jnp.maximum(xnorm, _TINY)
     unbounded = (
         at_period
         & (xnorm >= DIVERGENCE_GUARD)
@@ -384,9 +417,9 @@ def pdhg_step(
     iters = iters + live.astype(jnp.int32)
 
     # --- (2) prox steps ----------------------------------------------------
-    x1 = jnp.maximum(x + tau[:, None] * (c - aty), 0.0)
+    x1 = jnp.maximum(x + tau * (c - aty), 0.0)
     ax1 = mv(a, x1)
-    y1 = jnp.maximum(y + sigma[:, None] * (2.0 * ax1 - ax - b), 0.0)
+    y1 = jnp.maximum(y + sigma * (2.0 * ax1 - ax - b), 0.0)
 
     # --- (3) restart-to-average bookkeeping --------------------------------
     cnt = inner + 1
@@ -394,14 +427,14 @@ def pdhg_step(
     ys1 = y_sum + y1
     axs1 = ax_sum + ax1
     do_restart = cnt >= restart
-    denom = cnt.astype(x.dtype)[:, None]
-    x2 = jnp.where(do_restart[:, None], xs1 / denom, x1)
-    y2 = jnp.where(do_restart[:, None], ys1 / denom, y1)
-    ax2 = jnp.where(do_restart[:, None], axs1 / denom, ax1)
+    denom = cnt.astype(x.dtype)
+    x2 = jnp.where(do_restart, xs1 / denom, x1)
+    y2 = jnp.where(do_restart, ys1 / denom, y1)
+    ax2 = jnp.where(do_restart, axs1 / denom, ax1)
     zero = jnp.zeros((), x.dtype)
-    xs2 = jnp.where(do_restart[:, None], zero, xs1)
-    ys2 = jnp.where(do_restart[:, None], zero, ys1)
-    axs2 = jnp.where(do_restart[:, None], zero, axs1)
+    xs2 = jnp.where(do_restart, zero, xs1)
+    ys2 = jnp.where(do_restart, zero, ys1)
+    axs2 = jnp.where(do_restart, zero, axs1)
     inner2 = jnp.where(do_restart, 0, cnt)
     # Growth gate: record the boundary norms (pre-averaging, the same
     # measure the certificate compares) for the next period's test.
@@ -409,13 +442,12 @@ def pdhg_step(
     yg2 = jnp.where(do_restart, ynorm, y_grow)
 
     # Freeze finished rows.
-    lv = live[:, None]
-    x = jnp.where(lv, x2, x)
-    y = jnp.where(lv, y2, y)
-    ax = jnp.where(lv, ax2, ax)
-    x_sum = jnp.where(lv, xs2, x_sum)
-    y_sum = jnp.where(lv, ys2, y_sum)
-    ax_sum = jnp.where(lv, axs2, ax_sum)
+    x = jnp.where(live, x2, x)
+    y = jnp.where(live, y2, y)
+    ax = jnp.where(live, ax2, ax)
+    x_sum = jnp.where(live, xs2, x_sum)
+    y_sum = jnp.where(live, ys2, y_sum)
+    ax_sum = jnp.where(live, axs2, ax_sum)
     inner = jnp.where(live, inner2, inner)
     x_grow = jnp.where(live, xg2, x_grow)
     y_grow = jnp.where(live, yg2, y_grow)
@@ -447,8 +479,11 @@ def iterate(
     tau, sigma, scales = step_sizes(a, b, c, mv=mv, rmv=rmv)
     bsz = a.shape[0]
     limit = static_cap if static_cap is not None else cap
-    status0 = jnp.full((bsz,), RUNNING, jnp.int32)
-    iters0 = jnp.zeros((bsz,), jnp.int32)
+
+    def col(v):  # (B,) per-LP scalar -> (B, 1), pdhg_step's convention
+        return v[:, None]
+
+    tau, sigma, scales = col(tau), col(sigma), tuple(col(s) for s in scales)
 
     def body(carry):
         x, y, ax, xs, ys, axs, inner, xg, yg, status, iters, step = carry
@@ -465,21 +500,22 @@ def iterate(
     carry0 = (
         state.x, state.y, state.ax,
         state.x_sum, state.y_sum, state.ax_sum,
-        state.inner, state.x_grow, state.y_grow,
-        status0, iters0, jnp.int32(0),
+        col(state.inner), col(state.x_grow), col(state.y_grow),
+        jnp.full((bsz, 1), RUNNING, jnp.int32), jnp.zeros((bsz, 1), jnp.int32),
+        jnp.int32(0),
     )
     x, y, ax, xs, ys, axs, inner, xg, yg, status, iters, _ = jax.lax.while_loop(
         cond, body, carry0
     )
-    status = jnp.where(status == RUNNING, ITER_LIMIT, status)
+    status = jnp.where(status[:, 0] == RUNNING, ITER_LIMIT, status[:, 0])
     pobj = jnp.sum(c * x, axis=-1)
     objective = jnp.where(status == OPTIMAL, pobj, -jnp.inf)
     sol = LPSolution(
-        objective=objective, x=x, status=status, iterations=iters, y=y
+        objective=objective, x=x, status=status, iterations=iters[:, 0], y=y
     )
     out_state = PDHGResumeState(
-        x=x, y=y, ax=ax, x_sum=xs, y_sum=ys, ax_sum=axs, inner=inner,
-        x_grow=xg, y_grow=yg,
+        x=x, y=y, ax=ax, x_sum=xs, y_sum=ys, ax_sum=axs, inner=inner[:, 0],
+        x_grow=xg[:, 0], y_grow=yg[:, 0],
     )
     return sol, out_state
 

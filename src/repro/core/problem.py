@@ -426,7 +426,9 @@ def canonicalize(problem: LPProblem) -> Canonicalized:
 
     lo0 = jnp.where(jnp.isfinite(p.lo), p.lo, 0.0).astype(dtype)  # shift
     free = jnp.isneginf(p.lo)  # (B, n)
-    a_lo = jnp.einsum("bmn,bn->bm", p.a, lo0)
+    a_lo = jnp.einsum(
+        "bmn,bn->bm", p.a, lo0, precision=jax.lax.Precision.HIGHEST
+    )
 
     fin_u = jnp.isfinite(p.bu)
     a_blocks = [jnp.where(fin_u[:, :, None], p.a, 0.0)]

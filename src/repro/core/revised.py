@@ -101,13 +101,13 @@ class RevisedResumeState:
 
 
 class _RState(NamedTuple):
-    binv: jnp.ndarray
-    basis: jnp.ndarray
-    xb: jnp.ndarray
-    phase: jnp.ndarray
-    status: jnp.ndarray
-    iters: jnp.ndarray
-    step: jnp.ndarray
+    binv: jnp.ndarray  # (B, m, m)
+    basis: jnp.ndarray  # (B, m, 1) int32 column
+    xb: jnp.ndarray  # (B, m, 1) column
+    phase: jnp.ndarray  # (B, 1, 1) int32
+    status: jnp.ndarray  # (B, 1, 1) int32
+    iters: jnp.ndarray  # (B, 1, 1) int32
+    step: jnp.ndarray  # () int32
 
 
 def state_bytes_per_lp(m: int, n: int, dtype=jnp.float32) -> int:
@@ -166,7 +166,7 @@ def _warm_state(
     bmat = jnp.moveaxis(jnp.take(ai, safe - 1, axis=1), 1, 0)  # (B, m, m)
     eye = jnp.broadcast_to(jnp.eye(m, dtype=dtype), (bsz, m, m))
     binv_u = jnp.linalg.solve(bmat, eye)
-    xb = jnp.einsum("bij,bj->bi", binv_u, b)
+    xb = jnp.einsum("bij,bj->bi", binv_u, b, precision=jax.lax.Precision.HIGHEST)
     sgn = _signs(b, dtype)
     binv = binv_u * sgn[:, None, :]  # column scaling into the signed system
     feas_tol = 1e-9 if dtype == jnp.float64 else 1e-6
@@ -205,8 +205,10 @@ def _basic_costs(
     n: int,
     gather: bool = True,
 ):
-    """(B, m) cost of each basic variable under the CURRENT phase.
+    """(B, m, 1) cost of each basic variable under the CURRENT phase.
 
+    ``basis``: (B, m, 1) column; ``phase``: (B, 1, 1); ``c``: (B, 1, n)
+    row — the rank-preserving orientation of ``core/engine.py``.
     Phase I: -1 per basic artificial (ID >= 1+n+m), 0 else.  Phase II:
     ``c[j]`` for original variables, 0 for slacks — and 0 for a
     still-basic degenerate artificial, matching ``phase2_objective``'s
@@ -219,22 +221,36 @@ def _basic_costs(
     cb1 = -(basis >= art_start).astype(dtype)
     is_var = (basis >= 1) & (basis <= n)
     if gather:
-        cvals = jnp.take_along_axis(c, jnp.clip(basis - 1, 0, n - 1), axis=-1)
+        cvals = jnp.take_along_axis(c, jnp.clip(basis - 1, 0, n - 1), axis=2)
     else:
-        var_ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n), 2)
-        hit = basis[:, :, None] - 1 == var_ids
-        cvals = jnp.sum(jnp.where(hit, c[:, None, :], 0.0), axis=-1)
+        hit = basis - 1 == engine.column_ids(n)  # (B, m, n)
+        cvals = jnp.sum(jnp.where(hit, c, 0.0), axis=2, keepdims=True)
     cb2 = jnp.where(is_var, cvals, 0.0)
-    return jnp.where((phase == 1)[:, None], cb1, cb2)
+    return jnp.where(phase == 1, cb1, cb2)
+
+
+def _price(w: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
+    """Row (B, 1, m) times the shared (m, n) ``A`` -> row (B, 1, n).
+
+    ONE 2-D GEMM for the whole batch on every target (the same form in
+    both drivers, so they round identically); ``HIGHEST`` keeps XLA's and
+    Mosaic's TPU matmuls at float32 precision instead of one bfloat16
+    pass.
+    """
+    bsz, _, m = w.shape
+    out = jax.lax.dot_general(
+        w.reshape(bsz, m), a, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=a.dtype,
+    )
+    return out.reshape(bsz, 1, a.shape[1])
 
 
 def iteration_step(
     a,
-    b,
     c,
     sgn,
     feas_tol,
-    elig,
     s: _RState,
     *,
     rule: str,
@@ -252,40 +268,42 @@ def iteration_step(
     ``core/engine.py`` blocks both tableau drivers share.  ``row0`` is
     the batch-row base keying the RPC noise, so a tiled kernel draws
     bitwise the same noise as the untiled XLA path.
+
+    Orientation (``core/engine.py``): ``binv`` (B, m, m); ``basis`` and
+    ``xb`` (B, m, 1) columns over basis rows; ``c`` (B, 1, n) and the
+    constraint signs ``sgn`` (B, 1, m) rows; per-LP scalars (B, 1, 1).
     """
     m, n = a.shape
-    bsz = b.shape[0]
+    bsz = c.shape[0]
     dtype = a.dtype
-    q = 1 + n + m  # compact column count: RHS + vars + slacks
     art_start = 1 + n + m
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+    rows = engine.row_ids(m)
 
     active = s.status == RUNNING
     p1 = s.phase == 1
 
     # Pricing: y = c_B . B^-1, then ONE shared GEMM against A.
-    cb = _basic_costs(s.basis, s.phase, c, m, n, gather=gather)
-    y = jax.lax.dot_general(
-        cb[:, None, :],
-        s.binv,
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=dtype,
-    )[:, 0, :]  # (B, m)
+    cb = _basic_costs(s.basis, s.phase, c, m, n, gather=gather)  # (B, m, 1)
+    y = jnp.sum(cb * s.binv, axis=1, keepdims=True)  # (B, 1, m)
     w = y * sgn
-    priced = jax.lax.dot_general(
-        w, a, (((1,), (0,)), ((), ())), preferred_element_type=dtype
-    )  # (B, n): every LP reads the SAME broadcast A
-    r_vars = jnp.where(p1[:, None], 0.0, c) - priced
+    priced = _price(w, a)  # (B, 1, n): every LP reads the SAME A
+    r_vars = jnp.where(p1, 0.0, c) - priced
     r_slack = -w
-    obj0 = -jnp.sum(cb * s.xb, axis=-1)  # == tab[:, m, 0] (the -z slot)
-    objrow = jnp.concatenate([obj0[:, None], r_vars, r_slack], axis=1)
+    obj0 = -jnp.sum(cb * s.xb, axis=1, keepdims=True)  # == tab[m, 0] (-z slot)
 
-    noise = (
-        engine.rpc_noise(seed, s.step, row0, bsz, q, dtype)
+    # The reduced-cost row is [obj0 | r_vars | r_slack] over q = 1 + n + m
+    # columns; only vars and slacks are eligible, so select per segment.
+    noises = (
+        [
+            engine.rpc_noise(seed, s.step, row0, bsz, n, dtype, col_offset=1),
+            engine.rpc_noise(seed, s.step, row0, bsz, m, dtype, col_offset=1 + n),
+        ]
         if rule == RPC
         else None
     )
-    e, max_c = engine.select_entering(objrow, elig, rule, tol, noise)
+    e, max_c = engine.select_entering_segments(
+        [r_vars, r_slack], [1, 1 + n], rule, tol, noises
+    )
     at_opt = max_c <= tol
 
     # Phase transition — no objective-row rewrite needed: pricing is
@@ -296,29 +314,30 @@ def iteration_step(
     status = jnp.where(active & at_opt & (s.phase == 2), OPTIMAL, status)
     new_phase = jnp.where(p1_done & feasible, 2, s.phase)
 
-    # Entering column u = B^-1 . (S M_e): gather ONE column of the
-    # shared A (or a signed slack one-hot), then an (m, m) matvec.
+    # Entering column u = B^-1 . (S M_e): pick ONE column of the shared A
+    # (or a signed slack one-hot) as a row over constraints, then an
+    # (m, m) matvec.
     is_var_e = e <= n
+    e_var = jnp.clip(e - 1, 0, n - 1)
     if gather:
-        col_a = jnp.take(a, jnp.clip(e - 1, 0, n - 1), axis=1).T  # (B, m)
+        col_a = jnp.take(a, e_var[:, 0, 0], axis=1).T[:, None, :]  # (B, 1, m)
     else:
-        col_ids = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-        oh = (col_ids == jnp.clip(e - 1, 0, n - 1)[:, None]).astype(dtype)
+        oh = (engine.column_ids(n) == e_var).astype(dtype)  # (B, 1, n)
         col_a = jax.lax.dot_general(
-            oh, a, (((1,), (1,)), ((), ())), preferred_element_type=dtype
-        )  # (B, m): one-hot row-combination of A's columns
-    col_s = (row_ids == jnp.clip(e - 1 - n, 0, m - 1)[:, None]).astype(dtype)
-    me = sgn * jnp.where(is_var_e[:, None], col_a, col_s)
-    u = jax.lax.dot_general(
-        s.binv, me, (((2,), (1,)), ((0,), (0,))), preferred_element_type=dtype
-    )  # (B, m)
+            oh.reshape(bsz, n), a, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=dtype,
+        ).reshape(bsz, 1, m)  # one-hot row-combination of A's columns
+    col_s = (engine.column_ids(m) == jnp.clip(e - 1 - n, 0, m - 1)).astype(dtype)
+    me = sgn * jnp.where(is_var_e, col_a, col_s)  # (B, 1, m)
+    u = jnp.sum(s.binv * me, axis=2, keepdims=True)  # (B, m, 1)
 
     # Ratio test — engine.ratio_test's formulas on (u, xb).
     ratios = jnp.where(u > tol, s.xb / jnp.where(u > tol, u, 1.0), engine.BIG)
     art_escape = (s.basis >= art_start) & (s.xb <= tol) & (u < -tol)
     ratios = jnp.where(art_escape, 0.0, ratios)
-    l = jnp.argmin(ratios, axis=-1).astype(jnp.int32)
-    min_ratio = jnp.min(ratios, axis=-1)
+    min_ratio = jnp.min(ratios, axis=1, keepdims=True)
+    l = engine.first_index(ratios == min_ratio, axis=1)
 
     pivoting = active & ~at_opt
     unbounded = pivoting & (min_ratio >= engine.BIG / 2)
@@ -328,49 +347,64 @@ def iteration_step(
     # Rank-1 product-form update — engine.pivot_update's formulas
     # applied to binv and xb (the tableau applies the identical
     # update to its B^-1-image columns and RHS).
-    pe = engine.take_elem(u, l, gather)
+    pe = engine.take_row(u, l, gather)  # (B, 1, 1)
     safe_pe = jnp.where(jnp.abs(pe) > tol, pe, 1.0)
-    pr = engine.take_row(s.binv, l, gather)
-    npr = pr / safe_pe[:, None]
-    upd_binv = s.binv - u[:, :, None] * npr[:, None, :]
-    l_rows = row_ids == l[:, None]  # (B, m)
-    upd_binv = jnp.where(l_rows[:, :, None], npr[:, None, :], upd_binv)
-    px = engine.take_elem(s.xb, l, gather)
+    pr = engine.take_row(s.binv, l, gather)  # (B, 1, m)
+    npr = pr / safe_pe
+    l_rows = rows == l  # (B, m, 1)
+    upd_binv = jnp.where(l_rows, npr, s.binv - u * npr)
+    px = engine.take_row(s.xb, l, gather)
     npx = px / safe_pe
-    upd_xb = jnp.where(l_rows, npx[:, None], s.xb - u * npx[:, None])
+    upd_xb = jnp.where(l_rows, npx, s.xb - u * npx)
 
-    binv = jnp.where(do_pivot[:, None, None], upd_binv, s.binv)
-    xb = jnp.where(do_pivot[:, None], upd_xb, s.xb)
-    basis = jnp.where(do_pivot[:, None] & l_rows, e[:, None], s.basis)
+    keep = engine.widen_rows(do_pivot, m)  # (B, m, 1)
+    binv = jnp.where(keep, upd_binv, s.binv)
+    xb = jnp.where(keep, upd_xb, s.xb)
+    basis = jnp.where(keep & l_rows, e, s.basis)
     iters = s.iters + do_pivot.astype(jnp.int32)
     return _RState(binv, basis, xb, new_phase, status, iters, s.step + 1)
 
 
-def finalize(
-    final: _RState, c, m: int, n: int, gather: bool = True, fill=-jnp.inf
-):
-    """Terminal (objective, x, status) from a finished loop state.
+def finalize(final: _RState, c, m: int, n: int, gather: bool = True):
+    """Terminal (x, status) from a finished loop state.
 
-    Shared by both drivers: ITER_LIMIT fill for rows still RUNNING,
-    phase-II objective ``c_B . x_B`` at the terminal basis (== the
-    tableau's ``-tab[m, 0]``), one-hot scatter of basic values into the
-    primal point, zeros for non-OPTIMAL rows.  ``fill`` is the
-    non-optimal objective sentinel — the Pallas kernel passes a finite
-    ``-BIG`` (re-masked to -inf by its wrapper), the XLA driver -inf.
+    Shared by both drivers, in :func:`iteration_step`'s orientation:
+    ITER_LIMIT for rows still RUNNING, and the one-hot scatter of basic
+    values into the primal point as a (B, 1, n) row, zeros for
+    non-OPTIMAL rows.  The objective is :func:`objective_value`, which
+    both drivers evaluate outside any kernel.
     """
-    bsz = final.basis.shape[0]
     status = jnp.where(final.status == RUNNING, ITER_LIMIT, final.status)
+    # The OPTIMAL mask widens along rows before it meets the lanes (see
+    # engine.extract_solution).
+    hit = (final.basis == engine.column_ids(n) + 1) & engine.widen_rows(
+        status == OPTIMAL, m
+    )  # (B, m, n)
+    x = jnp.sum(jnp.where(hit, final.xb, 0.0), axis=1, keepdims=True)
+    return x, status
+
+
+def objective_value(basis, xb, status, c, m: int, n: int) -> jnp.ndarray:
+    """Phase-II objective ``c_B . x_B`` at a terminal basis, -inf unless OPTIMAL.
+
+    (B, m) ``basis``/``xb``, (B,) ``status``, (B, n) ``c``.  Both drivers
+    call this one XLA function — the Pallas kernel's wrapper on the
+    kernel's exact (basis, xb) outputs — because a multi-term reduction
+    lowered inside the kernel may reassociate differently; this way both
+    backends return the same floats.  Equals the tableau's ``-tab[m, 0]``.
+    """
+    bsz = basis.shape[0]
     cb2 = _basic_costs(
-        final.basis, jnp.full((bsz,), 2, jnp.int32), c, m, n, gather=gather
-    )
-    objective = jnp.where(
-        status == OPTIMAL, jnp.sum(cb2 * final.xb, axis=-1), fill
-    )
-    var_ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n), 2)
-    hit = final.basis[:, :, None] == var_ids + 1
-    x = jnp.sum(jnp.where(hit, final.xb[:, :, None], 0.0), axis=1)
-    x = jnp.where((status == OPTIMAL)[:, None], x, 0.0)
-    return objective, x, status
+        basis[:, :, None], jnp.full((bsz, 1, 1), 2, jnp.int32), c[:, None, :], m, n
+    )[:, :, 0]
+    # An explicit left fold, not a reduce: XLA may vectorize a reduce
+    # differently depending on the program around it, while a chain of
+    # adds rounds the same way in every caller.
+    prod = cb2 * xb
+    total = prod[:, 0]
+    for i in range(1, m):
+        total = total + prod[:, i]
+    return jnp.where(status == OPTIMAL, total, jnp.asarray(-jnp.inf, c.dtype))
 
 
 def _iterate(
@@ -388,26 +422,26 @@ def _iterate(
     m, n = a.shape
     bsz = b.shape[0]
     dtype = a.dtype
-    q = 1 + n + m
     limit = static_cap if static_cap is not None else cap
-    sgn = _signs(b, dtype)
-    elig = engine.eligible_mask(q, m, n)
+    sgn = _signs(b, dtype)[:, None, :]
+    c3 = c[:, None, :]
+    feas_tol = feas_tol[:, None, None]
 
     def cond(s: _RState):
         return (s.step < limit) & jnp.any(s.status == RUNNING)
 
     def body(s: _RState):
         return iteration_step(
-            a, b, c, sgn, feas_tol, elig, s, rule=rule, tol=tol, seed=seed
+            a, c3, sgn, feas_tol, s, rule=rule, tol=tol, seed=seed
         )
 
     init = _RState(
         binv=state.binv,
-        basis=state.basis,
-        xb=state.xb,
-        phase=state.phase,
-        status=jnp.full((bsz,), RUNNING, jnp.int32),
-        iters=jnp.zeros((bsz,), jnp.int32),
+        basis=state.basis[:, :, None],
+        xb=state.xb[:, :, None],
+        phase=state.phase[:, None, None],
+        status=jnp.full((bsz, 1, 1), RUNNING, jnp.int32),
+        iters=jnp.zeros((bsz, 1, 1), jnp.int32),
         step=jnp.asarray(0, jnp.int32),
     )
     if unroll > 1:
@@ -420,15 +454,16 @@ def _iterate(
 
     final = jax.lax.while_loop(cond, body, init)
 
-    objective, x, status = finalize(final, c, m, n)
+    x, status = finalize(final, c3, m, n)
+    basis, xb, status = final.basis[:, :, 0], final.xb[:, :, 0], status[:, 0, 0]
     sol = LPSolution(
-        objective=objective,
-        x=x,
+        objective=objective_value(basis, xb, status, c, m, n),
+        x=x[:, 0, :],
         status=status,
-        iterations=final.iters,
-        basis=final.basis,
+        iterations=final.iters[:, 0, 0],
+        basis=basis,
     )
-    return sol, RevisedResumeState(final.binv, final.basis, final.xb, final.phase)
+    return sol, RevisedResumeState(final.binv, basis, xb, final.phase[:, 0, 0])
 
 
 @functools.partial(

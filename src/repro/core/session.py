@@ -287,9 +287,9 @@ def _sweep_jit(
         # Re-price the carried tableau's objective row for this step's
         # costs; body rows are reused as-is (same constraints).
         warm_obj = _engine.phase2_objective(
-            prev_tab, prev_basis, spec, c_ext, gather=True
+            prev_tab, prev_basis[:, :, None], spec, c_ext, gather=True
         )
-        warm_tab = prev_tab.at[:, m, :].set(warm_obj)
+        warm_tab = prev_tab.at[:, m, :].set(warm_obj[:, 0, :])
         tab = jnp.where(warm[:, None, None], warm_tab, cold_tab)
         basis = jnp.where(warm[:, None], prev_basis, cold_basis)
         phase = jnp.where(warm, 2, cold_phase)

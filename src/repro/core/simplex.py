@@ -52,10 +52,10 @@ from .tableau import DEFAULT_LAYOUT, TableauSpec
 
 class _State(NamedTuple):
     tab: jnp.ndarray  # (B, m+1, q)
-    basis: jnp.ndarray  # (B, m) int32
-    phase: jnp.ndarray  # (B,) int32 (1 or 2)
-    status: jnp.ndarray  # (B,) int32
-    iters: jnp.ndarray  # (B,) int32
+    basis: jnp.ndarray  # (B, m, 1) int32 column vector
+    phase: jnp.ndarray  # (B, 1, 1) int32 (1 or 2)
+    status: jnp.ndarray  # (B, 1, 1) int32
+    iters: jnp.ndarray  # (B, 1, 1) int32
     step: jnp.ndarray  # () int32
 
 
@@ -67,9 +67,9 @@ def resolve_cap(max_iters, m: int, n: int):
 
 
 def _phase2_costs(c: jnp.ndarray, spec: TableauSpec) -> jnp.ndarray:
-    """(B, spec.q) extended phase-II cost row (zeros outside columns 1..n)."""
+    """(B, 1, spec.q) extended phase-II cost row (zeros outside columns 1..n)."""
     bsz, n = c.shape
-    return jnp.zeros((bsz, spec.q), c.dtype).at[:, 1 : 1 + n].set(c)
+    return jnp.zeros((bsz, 1, spec.q), c.dtype).at[:, 0, 1 : 1 + n].set(c)
 
 
 def _iterate(
@@ -91,6 +91,7 @@ def _iterate(
     limit = static_cap if static_cap is not None else cap
 
     elig = engine.eligible_mask(tab.shape[2], m, n)
+    feas_tol = feas_tol[:, None, None]
 
     def cond(s: _State):
         return (s.step < limit) & jnp.any(s.status == RUNNING)
@@ -102,7 +103,9 @@ def _iterate(
             if rule == RPC
             else None
         )
-        e, max_c = engine.select_entering(s.tab[:, m, :], elig, rule, tol, noise)
+        e, max_c = engine.select_entering(
+            s.tab[:, m : m + 1, :], elig, rule, tol, noise
+        )
         at_opt = max_c <= tol
 
         new_tab, new_phase, status = engine.phase_transition(
@@ -111,9 +114,7 @@ def _iterate(
         )
 
         pivoting = active & ~at_opt
-        l, min_ratio, full_col = engine.ratio_test(
-            new_tab, s.basis, e, spec, tol, gather=True
-        )
+        l, min_ratio, full_col = engine.ratio_test(new_tab, s.basis, e, spec, tol)
         unbounded = pivoting & (min_ratio >= engine.BIG / 2)
         status = jnp.where(unbounded, UNBOUNDED, status)
         do_pivot = pivoting & ~unbounded
@@ -126,10 +127,10 @@ def _iterate(
 
     init = _State(
         tab=tab,
-        basis=basis,
-        phase=phase,
-        status=jnp.full((bsz,), RUNNING, jnp.int32),
-        iters=jnp.zeros((bsz,), jnp.int32),
+        basis=basis[:, :, None],
+        phase=phase[:, None, None],
+        status=jnp.full((bsz, 1, 1), RUNNING, jnp.int32),
+        iters=jnp.zeros((bsz, 1, 1), jnp.int32),
         step=jnp.asarray(0, jnp.int32),
     )
     if unroll > 1:
@@ -148,14 +149,15 @@ def _iterate(
     objective, x = engine.extract_solution(
         final.tab, final.basis, status, spec, n, fill=-jnp.inf
     )
+    basis_out = final.basis[:, :, 0]
     sol = LPSolution(
-        objective=objective,
-        x=x,
-        status=status,
-        iterations=final.iters,
-        basis=final.basis,
+        objective=objective[:, 0, 0],
+        x=x[:, 0, :],
+        status=status[:, 0, 0],
+        iterations=final.iters[:, 0, 0],
+        basis=basis_out,
     )
-    return sol, ResumeState(final.tab, final.basis, final.phase)
+    return sol, ResumeState(final.tab, basis_out, final.phase[:, 0, 0])
 
 
 def solve_traced(

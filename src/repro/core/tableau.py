@@ -42,6 +42,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 #: Valid tableau layouts (see module docstring).
@@ -277,7 +278,9 @@ def _warm_tableau(
 
     c_full = jnp.zeros((bsz, 1 + n + m), dtype).at[:, 1 : 1 + n].set(c)
     cb = jnp.take_along_axis(c_full, safe, axis=1)  # (B, m) basic costs
-    obj = c_full - jnp.einsum("bm,bmk->bk", cb, body)  # col 0 holds -z0
+    obj = c_full - jnp.einsum(  # col 0 holds -z0
+        "bm,bmk->bk", cb, body, precision=jax.lax.Precision.HIGHEST
+    )
 
     tab = jnp.zeros((bsz, m + 1, q), dtype)
     tab = tab.at[:, :m, : 1 + n + m].set(body)

@@ -14,12 +14,15 @@ This is also where the tableau storage layer (``core/tableau.py``) meets
 the hardware: all padded shapes derive from a ``TableauSpec``, the VMEM
 cost of one LP inside the kernel is estimated by
 :func:`kernel_vmem_bytes_per_lp`, and the batch tile is sized from that
-estimate (:func:`auto_tile_b`) instead of a fixed ``tile_b=8`` — under
-the compact layout more LPs fit per tile, which is the kernel-level
-payoff of dropping the artificial block.  Shapes whose SINGLE-LP
-footprint exceeds the budget report ``fits_vmem() == False``; the
-``pallas`` backend (``core/backends.py``) routes those to ``xla``
-instead of failing.
+estimate and from Mosaic's compile time (:func:`auto_tile_b`): small
+LPs share a tile by the dozen, large ones run 8 to a tile.  Shapes
+whose smallest legal tile (8 LPs) exceeds the budget report
+``fits_vmem() == False``; the ``pallas`` backend (``core/backends.py``)
+routes those to ``xla`` instead of failing.
+
+A batch sharded across a mesh launches each kernel once per device over
+its own rows (:func:`_launch_split`): Mosaic kernels have no GSPMD
+partitioning rule.
 """
 
 from __future__ import annotations
@@ -29,6 +32,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core import engine, pdhg, revised
 from ..core.bucketing import next_pow2
@@ -49,13 +53,138 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
-#: Per-core VMEM capacity the kernel plans against (~16 MB on current
-#: TPUs — see the Pallas guide).  Overridable for tests / other parts.
-VMEM_BUDGET_BYTES = int(os.environ.get("REPRO_VMEM_BUDGET_BYTES", 16 * 2**20))
+#: Scoped-VMEM limit every kernel is compiled under
+#: (``pltpu.CompilerParams(vmem_limit_bytes=...)``) and the capacity the
+#: tile rules below plan against — one number, so a tile the planner
+#: accepts is a tile Mosaic is allowed to allocate.  96 MiB is three
+#: quarters of a v5e TensorCore's 128 MiB of VMEM.  Overridable for tests
+#: / other parts.
+VMEM_BUDGET_BYTES = int(os.environ.get("REPRO_VMEM_BUDGET_BYTES", 96 * 2**20))
 
-#: Fraction of the budget one tile may claim — headroom for Mosaic
-#: temporaries, semaphores, and the compiler's own double-buffering.
-VMEM_TILE_FRACTION = 0.5
+#: Fraction of the budget the per-LP estimates may fill — headroom for
+#: what they do not count (the small per-tile blocks, semaphores) and
+#: for their own calibration error.
+VMEM_TILE_FRACTION = 0.8
+
+#: Smallest batch tile Mosaic accepts when a tile covers only part of
+#: the batch: per-LP data rides in 2-D blocks whose batch axis is the
+#: sublane axis, and a partial block must span whole 8-sublane tiles.
+MIN_TILE_B = 8
+
+#: Most bytes of per-LP blocks one batch tile may span (see
+#: :func:`_budget_tile`): about 256 vregs per elementwise op, which keeps
+#: each kernel's Mosaic compile within seconds (m = n = 100 tableau:
+#: 3 s at a tile of 8, 38 s at 64, compiled for a v5e).
+TILE_BLOCK_BYTES = 1 << 20
+
+
+def legal_tile_b(tile_b: int, bsz: int) -> int:
+    """The nearest tile >= ``tile_b`` that Mosaic accepts for ``bsz`` LPs.
+
+    A tile covering the whole batch is always legal (its blocks equal
+    the padded array); any other tile is rounded up to a multiple of
+    :data:`MIN_TILE_B`.  Results never depend on the tile, so this only
+    ever changes how the batch is cut.
+    """
+    tile_b = max(1, int(tile_b))
+    if tile_b >= bsz:
+        return tile_b
+    return _round_up(tile_b, MIN_TILE_B)
+
+
+def _budget_tile(bsz: int, per_lp: int, block: int, fixed: int = 0) -> int:
+    """Largest legal power-of-two tile (<= 128) that fits both budgets.
+
+    ``fixed`` bytes of VMEM are charged once per tile (a shared block),
+    ``per_lp`` bytes per LP.  ``block`` is the VMEM size of one LP's
+    largest block (tableau, ``A`` or ``B^-1``): a tile spans at most
+    :data:`TILE_BLOCK_BYTES` of it, because Mosaic unrolls every
+    elementwise op over the tile's vregs and its compile time grows
+    faster than linearly with that count.  Clamped down to the
+    pow-2-padded batch, and up to the smallest legal tile — shapes where
+    even that busts the VMEM budget are the router's problem
+    (:func:`fits_vmem` and its twins), not the tiler's.
+    """
+    budget = int(VMEM_BUDGET_BYTES * VMEM_TILE_FRACTION) - fixed
+    fit = max(1, min(budget // max(per_lp, 1), TILE_BLOCK_BYTES // max(block, 1)))
+    tile = 1 << (fit.bit_length() - 1)  # largest power of two <= fit
+    return legal_tile_b(min(tile, 128, next_pow2(bsz)), bsz)
+
+
+def _fits(per_lp: int, fixed: int = 0) -> bool:
+    """Whether the smallest partial tile (:data:`MIN_TILE_B` LPs) fits."""
+    return fixed + MIN_TILE_B * per_lp <= int(VMEM_BUDGET_BYTES * VMEM_TILE_FRACTION)
+
+
+# ---------------------------------------------------------------------------
+# batch-sharded launches: one kernel per device over its own rows
+# ---------------------------------------------------------------------------
+
+
+def _batch_split(x):
+    """``(mesh, axes)`` when ``x``'s batch axis is split across a mesh, else None."""
+    sh = getattr(x, "sharding", None)
+    if not isinstance(sh, jax.sharding.NamedSharding) or not sh.spec:
+        return None
+    axes = sh.spec[0]
+    return None if axes is None else (sh.mesh, axes)
+
+
+def _shards(split) -> int:
+    """Number of pieces ``split`` cuts the batch into (1 when unsplit)."""
+    if split is None:
+        return 1
+    mesh, axes = split
+    names = axes if isinstance(axes, tuple) else (axes,)
+    return int(np.prod([mesh.shape[a] for a in names]))
+
+
+#: Per-shard launchers built so far, keyed by entry, mesh, axes and the
+#: entry's static arguments — built once, so a repeat launch reuses its
+#: compiled program.
+_SHARDED: dict = {}
+
+
+def _launch_split(entry, split, cap, shared, batched, **statics):
+    """Call a jitted kernel entry, once per device when the batch is split.
+
+    Mosaic kernels have no rule for GSPMD to partition them by, so a
+    batch sharded across a mesh runs under ``shard_map``: each device
+    launches the kernel over its own rows, with no cross-device work.
+    ``entry`` is called as ``entry(*shared, *batched, cap, **statics)``;
+    ``shared`` arguments are replicated, ``batched`` ones split along
+    their first axis.  Where ``cap`` has a second entry (the first
+    global row of the launch, keying the RPC noise), each shard sets it
+    to its own first row, so results match the unsplit launch.
+    """
+    if split is None:
+        return entry(*shared, *batched, cap, **statics)
+    mesh, axes = split
+    key = (entry, mesh, axes, tuple(sorted(statics.items())))
+    fn = _SHARDED.get(key)
+    if fn is None:
+        rows = jax.sharding.PartitionSpec(axes)
+        whole = jax.sharding.PartitionSpec()
+
+        def local(cap, shared, batched):
+            size = jax.tree_util.tree_leaves(batched)[0].shape[0]
+            base = jax.lax.axis_index(axes) * size
+            cap = jnp.concatenate([cap[:1], cap[1:] + base])
+            return entry(*shared, *batched, cap, **statics)
+
+        fn = _SHARDED[key] = jax.jit(
+            jax.shard_map(
+                local, mesh=mesh, in_specs=(whole, whole, rows), out_specs=rows,
+                check_vma=False,
+            )
+        )
+    return fn(cap, shared, batched)
+
+
+def _split_cache_size(entries) -> int:
+    """Compiled per-shard launchers of the given entries."""
+    return sum(int(fn._cache_size()) for key, fn in _SHARDED.items() if key[0] in entries)
+
 
 
 def _pad_shapes(bsz: int, spec: TableauSpec, tile_b: int):
@@ -68,24 +197,32 @@ def _pad_shapes(bsz: int, spec: TableauSpec, tile_b: int):
     )
 
 
+def _tiled_bytes(rows: int, cols: int, item: int) -> int:
+    """VMEM bytes of a (rows, cols) value stored in (8, 128) vreg tiles."""
+    return _round_up(rows, 8) * _round_up(cols, 128) * item
+
+
 def kernel_vmem_bytes_per_lp(
     spec: TableauSpec, dtype=jnp.float32, want_state: bool = False
 ) -> int:
     """Estimated VMEM bytes ONE LP occupies inside the simplex kernel.
 
-    Counts the lane/sublane-padded tableau block twice (the BlockSpec
-    input plus the ``while_loop`` carry; three times with the
-    ``want_state`` output block), the extended cost row, the primal
-    output row, and the int32 basis/status/iters vectors.  An estimate —
-    Mosaic's actual allocation includes temporaries — which is why
-    :data:`VMEM_TILE_FRACTION` keeps headroom.
+    Calibrated against Mosaic's scoped-VMEM allocation for the v5e
+    (compiled for a described chip, ~20% above the smallest limit that
+    compiles at m = n = 28, 100, 200): four tableau-sized blocks (the
+    single-buffered input plus the pivot's working copies; two more with
+    the ``want_state`` output), six (rows, 1) column vectors (pivot
+    column, ratios, basis, masks — each pads to whole (8, 128) tiles)
+    and eight (1, 1) per-LP scalars, one vreg tile each.
     """
-    qp, m1p, mp, np_pad, _ = _pad_shapes(1, spec, 1)
+    qp, m1p, _, _, _ = _pad_shapes(1, spec, 1)
     item = jnp.dtype(dtype).itemsize
-    tab_copies = 3 if want_state else 2
-    f32_bytes = (tab_copies * m1p * qp + qp + np_pad) * item
-    i32_bytes = 4 * (2 * mp + 4)  # basis in/out + phase/status/iters/obj
-    return f32_bytes + i32_bytes
+    tabs = 6 if want_state else 4
+    return (
+        tabs * _tiled_bytes(m1p, qp, item)
+        + 6 * _tiled_bytes(m1p, 1, item)
+        + 8 * _tiled_bytes(1, 1, item)
+    )
 
 
 def fits_vmem(
@@ -95,14 +232,13 @@ def fits_vmem(
     layout: str = DEFAULT_LAYOUT,
     want_state: bool = False,
 ) -> bool:
-    """Whether a single LP of this shape fits the kernel's VMEM budget.
+    """Whether a tile of :data:`MIN_TILE_B` LPs of this shape fits VMEM.
 
     The routing predicate the ``pallas`` backend consults before
-    launching: a shape that cannot fit even one LP per tile is dispatched
-    to the ``xla`` backend instead of failing inside Mosaic.
+    launching: a shape that cannot fit the smallest legal tile is
+    dispatched to the ``xla`` backend instead of failing inside Mosaic.
     """
-    per_lp = kernel_vmem_bytes_per_lp(TableauSpec(m, n, layout), dtype, want_state)
-    return per_lp <= int(VMEM_BUDGET_BYTES * VMEM_TILE_FRACTION)
+    return _fits(kernel_vmem_bytes_per_lp(TableauSpec(m, n, layout), dtype, want_state))
 
 
 def auto_tile_b(
@@ -110,13 +246,14 @@ def auto_tile_b(
 ) -> int:
     """VMEM-budget-aware batch tile: largest power of two that fits.
 
-    Replaces the historical fixed ``tile_b=8``: the tile is sized so
-    ``tile_b * kernel_vmem_bytes_per_lp`` stays within the tile's share
-    of VMEM, capped at 128 (diminishing returns past a full lane vector)
-    and clamped down to the (power-of-two-padded) batch so small batches
-    run as one small tile rather than padding up to a full-size tile.
-    Never returns less than 1 — un-fittable shapes are the backend
-    router's problem (:func:`fits_vmem`), not the tiler's.
+    The tile is sized so ``tile_b * kernel_vmem_bytes_per_lp`` stays
+    within the tile's share of VMEM and the tile's tableaus within
+    :data:`TILE_BLOCK_BYTES`, capped at 128 and clamped down to the
+    (power-of-two-padded) batch so small batches run as one small tile
+    rather than padding up to a full-size tile (:func:`_budget_tile`).
+    Never returns a tile Mosaic refuses
+    (:func:`legal_tile_b`) — un-fittable shapes are the backend router's
+    problem (:func:`fits_vmem`), not the tiler's.
 
     A MEASURED winning tile from the autotuner's cache
     (``runtime/autotune.py:cached_tile_b``) overrides the heuristic when
@@ -129,12 +266,13 @@ def auto_tile_b(
 
     tuned = _autotune.cached_tile_b(bsz, spec.m, spec.n, dtype, spec.layout)
     if tuned is not None:
-        return tuned
-    per_lp = kernel_vmem_bytes_per_lp(spec, dtype, want_state)
-    budget = int(VMEM_BUDGET_BYTES * VMEM_TILE_FRACTION)
-    fit = max(1, budget // max(per_lp, 1))
-    tile = 1 << (fit.bit_length() - 1)  # largest power of two <= fit
-    return max(1, min(tile, 128, next_pow2(bsz)))
+        return legal_tile_b(tuned, bsz)
+    qp, m1p, _, _, _ = _pad_shapes(1, spec, 1)
+    return _budget_tile(
+        bsz,
+        kernel_vmem_bytes_per_lp(spec, dtype, want_state),
+        _tiled_bytes(m1p, qp, jnp.dtype(dtype).itemsize),
+    )
 
 
 def _pad_launch_inputs(tab, basis, phase, b, c, spec: TableauSpec, tile_b: int):
@@ -155,10 +293,10 @@ def _pad_launch_inputs(tab, basis, phase, b, c, spec: TableauSpec, tile_b: int):
     # sit AFTER it and stay zero (never selected: their pivot column is 0).
     tab_p = tab_p.at[:bsz, : m + 1, :q].set(tab)
     basis_p = jnp.zeros((bp, mp), jnp.int32).at[:bsz, :m].set(basis)
-    phase_p = jnp.full((bp,), 2, jnp.int32).at[:bsz].set(phase)
+    phase_p = jnp.full((bp, 1), 2, jnp.int32).at[:bsz, 0].set(phase)
     c_ext = jnp.zeros((bp, qp), dtype).at[:bsz, 1 : 1 + n].set(c)
     feas = engine.phase1_feasibility_tol(b).astype(dtype)
-    feas_p = jnp.ones((bp,), dtype).at[:bsz].set(feas)
+    feas_p = jnp.ones((bp, 1), dtype).at[:bsz, 0].set(feas)
     return tab_p, basis_p, phase_p, c_ext, feas_p, np_pad
 
 
@@ -183,17 +321,19 @@ def _launch(
         tol=tol,
         static_cap=static_cap,
         want_state=want_state,
+        vmem_limit_bytes=VMEM_BUDGET_BYTES,
         interpret=interpret,
     )
     obj, x, status, iters, basis_out = outs[:5]
     dtype = tab_p.dtype
     neg_inf = jnp.asarray(-jnp.inf, dtype)
-    objective = jnp.where(status[:bsz] == 1, obj[:bsz], neg_inf)
+    status = status[:bsz, 0]
+    objective = jnp.where(status == 1, obj[:bsz, 0], neg_inf)
     sol = LPSolution(
         objective=objective,
         x=x[:bsz, :n],
-        status=status[:bsz],
-        iterations=iters[:bsz],
+        status=status,
+        iterations=iters[:bsz, 0],
         basis=basis_out[:bsz, :m],
     )
     if not want_state:
@@ -202,7 +342,7 @@ def _launch(
     state = ResumeState(
         tab=tab_out[:bsz, : m + 1, : spec.q],
         basis=basis_out[:bsz, :m],
-        phase=phase_out[:bsz],
+        phase=phase_out[:bsz, 0],
     )
     return sol, state
 
@@ -258,7 +398,11 @@ def compile_cache_size() -> int:
     The ``pallas`` backend's hook behind ``SolveStats.compiles`` /
     ``SolveStats.cache_hits``.
     """
-    return int(_solve_jit._cache_size()) + int(_resume_jit._cache_size())
+    return (
+        int(_solve_jit._cache_size())
+        + int(_resume_jit._cache_size())
+        + _split_cache_size((_solve_jit, _resume_jit))
+    )
 
 
 def simplex_solve(
@@ -304,16 +448,19 @@ def simplex_solve(
     if interpret is None:
         interpret = not _on_tpu()
     bsz, m, n = a.shape
+    split = _batch_split(b)
+    bsz //= _shards(split)
     spec = TableauSpec(m, n, layout)
     if tile_b is None:
         tile_b = auto_tile_b(bsz, spec, a.dtype, want_state)
+    tile_b = legal_tile_b(tile_b, bsz)
     cap = resolve_cap(max_iters, m, n)
     if tol <= 0.0:
         tol = engine.default_tolerance(a.dtype)
     static_cap = None if dynamic_cap else int(cap)
-    cap_arr = jnp.full((1,), cap if dynamic_cap else 0, jnp.int32)
-    return _solve_jit(
-        a, b, c, basis0, cap_arr,
+    cap_arr = jnp.array([cap if dynamic_cap else 0, 0], jnp.int32)
+    return _launch_split(
+        _solve_jit, split, cap_arr, (), (a, b, c, basis0),
         spec=spec, rule=rule, seed=seed, tol=tol, tile_b=tile_b,
         static_cap=static_cap, want_state=want_state, interpret=interpret,
     )
@@ -344,17 +491,20 @@ def simplex_resume(
     if interpret is None:
         interpret = not _on_tpu()
     bsz, m = state.basis.shape
+    split = _batch_split(b)
+    bsz //= _shards(split)
     n = c.shape[-1]
     spec = TableauSpec.from_tableau(m, n, state.tab.shape[-1])
     if tile_b is None:
         tile_b = auto_tile_b(bsz, spec, state.tab.dtype, want_state)
+    tile_b = legal_tile_b(tile_b, bsz)
     cap = resolve_cap(max_iters, m, n)
     if tol <= 0.0:
         tol = engine.default_tolerance(state.tab.dtype)
     static_cap = None if dynamic_cap else int(cap)
-    cap_arr = jnp.full((1,), cap if dynamic_cap else 0, jnp.int32)
-    return _resume_jit(
-        b, c, state, cap_arr,
+    cap_arr = jnp.array([cap if dynamic_cap else 0, 0], jnp.int32)
+    return _launch_split(
+        _resume_jit, split, cap_arr, (), (b, c, state),
         spec=spec, rule=rule, seed=seed, tol=tol, tile_b=tile_b,
         static_cap=static_cap, want_state=want_state, interpret=interpret,
     )
@@ -372,36 +522,38 @@ def _pdhg_pad_shapes(bsz: int, m: int, n: int, tile_b: int):
 def pdhg_vmem_bytes_per_lp(m: int, n: int, dtype=jnp.float32) -> int:
     """Estimated VMEM bytes ONE LP occupies inside the PDHG kernel.
 
-    Counts the lane/sublane-padded data block A twice (BlockSpec input
-    plus Mosaic's working copy), b and c once, and three copies of the
-    six iterate vectors (input block, ``while_loop`` carry, output
-    block).  The first-order counterpart of
-    :func:`kernel_vmem_bytes_per_lp` — O(m n) with a small constant
-    where the tableau is O(m (n + m)), which is exactly why large shapes
-    route here (see ``core/backends.py:route_shape``).
+    Calibrated like :func:`kernel_vmem_bytes_per_lp` (~20% above what
+    Mosaic allocates for the v5e at m = n = 28, 100, 500): three
+    A-sized blocks (the single-buffered input and the matvec products),
+    twenty (m, 1) dual-side column vectors — iterates, running sums and
+    their temporaries, each padded to whole (8, 128) tiles — and
+    forty-eight (1, 1) per-LP scalars.  The first-order counterpart of
+    the tableau estimate: O(m n) where the tableau is O(m (n + m)),
+    which is exactly why large shapes route here (see
+    ``core/backends.py:route_shape``).
     """
     mp, np_pad, _ = _pdhg_pad_shapes(1, m, n, 1)
     item = jnp.dtype(dtype).itemsize
-    f32_bytes = (
-        2 * mp * np_pad + mp + np_pad + 3 * (2 * np_pad + 4 * mp + 2)
-    ) * item
-    i32_bytes = 4 * 4  # inner in/out + status + iters
-    return f32_bytes + i32_bytes
+    return (
+        3 * _tiled_bytes(mp, np_pad, item)
+        + 20 * _tiled_bytes(mp, 1, item)
+        + 48 * _tiled_bytes(1, 1, item)
+    )
 
 
 def pdhg_fits_vmem(m: int, n: int, dtype=jnp.float32) -> bool:
-    """Whether a single LP of this shape fits the PDHG kernel's budget."""
-    per_lp = pdhg_vmem_bytes_per_lp(m, n, dtype)
-    return per_lp <= int(VMEM_BUDGET_BYTES * VMEM_TILE_FRACTION)
+    """Whether a :data:`MIN_TILE_B`-LP tile of this shape fits the PDHG kernel."""
+    return _fits(pdhg_vmem_bytes_per_lp(m, n, dtype))
 
 
 def pdhg_auto_tile_b(bsz: int, m: int, n: int, dtype=jnp.float32) -> int:
-    """VMEM-budget-aware batch tile for the PDHG kernel (pow-2, <= 128)."""
-    per_lp = pdhg_vmem_bytes_per_lp(m, n, dtype)
-    budget = int(VMEM_BUDGET_BYTES * VMEM_TILE_FRACTION)
-    fit = max(1, budget // max(per_lp, 1))
-    tile = 1 << (fit.bit_length() - 1)  # largest power of two <= fit
-    return max(1, min(tile, 128, next_pow2(bsz)))
+    """VMEM-budget-aware batch tile for the PDHG kernel (pow-2, <= 128, legal)."""
+    mp, np_pad, _ = _pdhg_pad_shapes(1, m, n, 1)
+    return _budget_tile(
+        bsz,
+        pdhg_vmem_bytes_per_lp(m, n, dtype),
+        _tiled_bytes(mp, np_pad, jnp.dtype(dtype).itemsize),
+    )
 
 
 def _pdhg_launch(a, b, c, state, cap, *, tol, restart, tile_b, static_cap,
@@ -426,8 +578,8 @@ def _pdhg_launch(a, b, c, state, cap, *, tol, restart, tile_b, static_cap,
     def pad_n(v):
         return jnp.zeros((bp, np_pad), dtype).at[:bsz, :n].set(v)
 
-    def pad_b(v):
-        return jnp.zeros((bp,), v.dtype).at[:bsz].set(v)
+    def pad_b(v):  # per-LP scalars travel as (B, 1) columns
+        return jnp.zeros((bp, 1), v.dtype).at[:bsz, 0].set(v)
 
     a_p = jnp.zeros((bp, mp, np_pad), dtype).at[:bsz, :m, :n].set(a)
     outs = pdhg_pallas(
@@ -437,10 +589,11 @@ def _pdhg_launch(a, b, c, state, cap, *, tol, restart, tile_b, static_cap,
         pad_b(state.inner), pad_b(state.x_grow), pad_b(state.y_grow),
         pad_b(tau), pad_b(sigma), pad_b(anorm), cap,
         tol=tol, restart=restart, tile_b=tile_b,
-        static_cap=static_cap, interpret=interpret,
+        static_cap=static_cap, vmem_limit_bytes=VMEM_BUDGET_BYTES,
+        interpret=interpret,
     )
     x, y, ax, xs, ys, axs, inner, xg, yg, status, iters = outs
-    x, status, iters = x[:bsz, :n], status[:bsz], iters[:bsz]
+    x, status, iters = x[:bsz, :n], status[:bsz, 0], iters[:bsz, 0]
     pobj = jnp.sum(c * x, axis=-1)
     objective = jnp.where(status == 1, pobj, jnp.asarray(-jnp.inf, dtype))
     sol = LPSolution(
@@ -451,7 +604,7 @@ def _pdhg_launch(a, b, c, state, cap, *, tol, restart, tile_b, static_cap,
     out_state = pdhg.PDHGResumeState(
         x=x, y=y[:bsz, :m], ax=ax[:bsz, :m],
         x_sum=xs[:bsz, :n], y_sum=ys[:bsz, :m], ax_sum=axs[:bsz, :m],
-        inner=inner[:bsz], x_grow=xg[:bsz], y_grow=yg[:bsz],
+        inner=inner[:bsz, 0], x_grow=xg[:bsz, 0], y_grow=yg[:bsz, 0],
     )
     return sol, out_state
 
@@ -489,7 +642,11 @@ def _pdhg_resume_jit(a, b, c, state, cap, *, tol, restart, tile_b, static_cap,
 
 def pdhg_compile_cache_size() -> int:
     """PDHG-kernel executables compiled so far (cold + resume paths)."""
-    return int(_pdhg_solve_jit._cache_size()) + int(_pdhg_resume_jit._cache_size())
+    return (
+        int(_pdhg_solve_jit._cache_size())
+        + int(_pdhg_resume_jit._cache_size())
+        + _split_cache_size((_pdhg_solve_jit, _pdhg_resume_jit))
+    )
 
 
 def pdhg_solve(
@@ -517,13 +674,16 @@ def pdhg_solve(
     if interpret is None:
         interpret = not _on_tpu()
     bsz, m, n = a.shape
+    split = _batch_split(b)
+    bsz //= _shards(split)
     if tile_b is None:
         tile_b = pdhg_auto_tile_b(bsz, m, n, a.dtype)
+    tile_b = legal_tile_b(tile_b, bsz)
     cap = pdhg.resolve_cap(max_iters, m, n)
     static_cap = None if dynamic_cap else int(cap)
     cap_arr = jnp.full((1,), cap if dynamic_cap else 0, jnp.int32)
-    return _pdhg_solve_jit(
-        a, b, c, cap_arr,
+    return _launch_split(
+        _pdhg_solve_jit, split, cap_arr, (), (a, b, c),
         tol=pdhg.resolve_tol(tol), restart=pdhg.resolve_restart(restart),
         tile_b=tile_b, static_cap=static_cap, want_state=want_state,
         interpret=interpret,
@@ -555,13 +715,16 @@ def pdhg_resume(
     if interpret is None:
         interpret = not _on_tpu()
     bsz, m, n = a.shape
+    split = _batch_split(b)
+    bsz //= _shards(split)
     if tile_b is None:
         tile_b = pdhg_auto_tile_b(bsz, m, n, a.dtype)
+    tile_b = legal_tile_b(tile_b, bsz)
     cap = pdhg.resolve_cap(max_iters, m, n)
     static_cap = None if dynamic_cap else int(cap)
     cap_arr = jnp.full((1,), cap if dynamic_cap else 0, jnp.int32)
-    return _pdhg_resume_jit(
-        a, b, c, state, cap_arr,
+    return _launch_split(
+        _pdhg_resume_jit, split, cap_arr, (), (a, b, c, state),
         tol=pdhg.resolve_tol(tol), restart=pdhg.resolve_restart(restart),
         tile_b=tile_b, static_cap=static_cap, want_state=want_state,
         interpret=interpret,
@@ -580,43 +743,46 @@ def _revised_pad_shapes(bsz: int, m: int, n: int, tile_b: int):
 def revised_shared_vmem_bytes(m: int, n: int, dtype=jnp.float32) -> int:
     """VMEM bytes the ONE shared ``A`` block claims per tile (not per LP).
 
-    Counted twice: the BlockSpec input plus Mosaic's working copy.  Paid
+    Counted twice: the pipeline's two buffers for the input block.  Paid
     once per tile regardless of ``tile_b`` — the amortization that lets
     :func:`revised_auto_tile_b` pack far more LPs per tile than the
     tableau kernel at the same shape.
     """
     mp, np_pad, _ = _revised_pad_shapes(1, m, n, 1)
-    return 2 * mp * np_pad * jnp.dtype(dtype).itemsize
+    return 2 * _tiled_bytes(mp, np_pad, jnp.dtype(dtype).itemsize)
 
 
 def revised_vmem_bytes_per_lp(m: int, n: int, dtype=jnp.float32) -> int:
     """Estimated VMEM bytes ONE LP occupies inside the revised kernel.
 
-    O(m²), not O(m·n): three copies of the (m, m) basis inverse (input
-    block, ``while_loop`` carry, output block), three of ``xb``, the
-    ``b``/``c``/``x`` rows, one re-priced objective row of q = 1+n+m
-    lanes, and the int32 basis/status vectors.  The shared ``A`` block
-    is NOT included — see :func:`revised_shared_vmem_bytes`.
+    O(m²), not O(m·n): four (m, m) basis-inverse blocks (the input
+    block's two buffers, the loop's working copy and the update), twelve
+    (m, 1) columns (the scratch state, the entering column, ratios — each
+    padded to whole (8, 128) tiles), six rows over ``n`` or ``m`` lanes
+    and eight per-LP scalars.  The shared ``A`` block is NOT included —
+    see :func:`revised_shared_vmem_bytes`.
     """
     mp, np_pad, _ = _revised_pad_shapes(1, m, n, 1)
     item = jnp.dtype(dtype).itemsize
-    qp = _round_up(1 + n + m, 128)
-    f32_bytes = (3 * mp * mp + 3 * mp + mp + 2 * np_pad + qp) * item
-    i32_bytes = 4 * (2 * mp + 4)  # basis in/out + phase/status/iters/step
-    return f32_bytes + i32_bytes
+    return (
+        4 * _tiled_bytes(mp, mp, item)
+        + 12 * _tiled_bytes(mp, 1, item)
+        + 6 * _tiled_bytes(1, max(np_pad, mp), item)
+        + 8 * _tiled_bytes(1, 1, item)
+    )
 
 
 def revised_fits_vmem(m: int, n: int, dtype=jnp.float32) -> bool:
-    """Whether the shared block plus a single LP fits the kernel budget.
+    """Whether the shared block plus a :data:`MIN_TILE_B`-LP tile fits the budget.
 
     The routing predicate ``route_shape(shared=True)`` and the
     ``pallas-shared`` backend consult: a shape that cannot fit the
-    shared ``A`` block and even one LP's basis state per tile runs the
-    XLA revised driver instead (bit-identical results).
+    shared ``A`` block and the smallest legal tile's basis state runs
+    the XLA revised driver instead (bit-identical results).
     """
-    per_tile = revised_shared_vmem_bytes(m, n, dtype)
-    per_lp = revised_vmem_bytes_per_lp(m, n, dtype)
-    return per_tile + per_lp <= int(VMEM_BUDGET_BYTES * VMEM_TILE_FRACTION)
+    return _fits(
+        revised_vmem_bytes_per_lp(m, n, dtype), revised_shared_vmem_bytes(m, n, dtype)
+    )
 
 
 def revised_auto_tile_b(bsz: int, m: int, n: int, dtype=jnp.float32) -> int:
@@ -626,12 +792,13 @@ def revised_auto_tile_b(bsz: int, m: int, n: int, dtype=jnp.float32) -> int:
     packed with O(m²) per-LP state.  Same pow-2/128-cap/batch-clamp
     conventions as :func:`auto_tile_b`.
     """
-    budget = int(VMEM_BUDGET_BYTES * VMEM_TILE_FRACTION)
-    budget -= revised_shared_vmem_bytes(m, n, dtype)
-    per_lp = revised_vmem_bytes_per_lp(m, n, dtype)
-    fit = max(1, budget // max(per_lp, 1))
-    tile = 1 << (fit.bit_length() - 1)  # largest power of two <= fit
-    return max(1, min(tile, 128, next_pow2(bsz)))
+    mp, _, _ = _revised_pad_shapes(1, m, n, 1)
+    return _budget_tile(
+        bsz,
+        revised_vmem_bytes_per_lp(m, n, dtype),
+        _tiled_bytes(mp, mp, jnp.dtype(dtype).itemsize),
+        revised_shared_vmem_bytes(m, n, dtype),
+    )
 
 
 def _revised_launch(a, b, c, state, cap, *, rule, seed, tol, tile_b,
@@ -656,33 +823,25 @@ def _revised_launch(a, b, c, state, cap, *, rule, seed, tol, tile_b,
     binv_p = jnp.zeros((bp, mp, mp), dtype).at[:bsz, :m, :m].set(state.binv)
     basis_p = jnp.zeros((bp, mp), jnp.int32).at[:bsz, :m].set(state.basis)
     xb_p = jnp.zeros((bp, mp), dtype).at[:bsz, :m].set(state.xb)
-    phase_p = jnp.full((bp,), 2, jnp.int32).at[:bsz].set(state.phase)
-    feas_p = jnp.ones((bp,), dtype).at[:bsz].set(feas)
+    phase_p = jnp.full((bp, 1), 2, jnp.int32).at[:bsz, 0].set(state.phase)
+    feas_p = jnp.ones((bp, 1), dtype).at[:bsz, 0].set(feas)
 
     outs = revised_pallas(
         a_p, b_p, c_p, binv_p, basis_p, xb_p, phase_p, feas_p, cap,
         m=m, n=n, rule=rule, seed=seed, tile_b=tile_b, tol=tol,
-        static_cap=static_cap, want_state=want_state, interpret=interpret,
+        static_cap=static_cap, want_state=want_state,
+        vmem_limit_bytes=VMEM_BUDGET_BYTES, interpret=interpret,
     )
     x, status, iters, basis_out, xb_out = outs[:5]
-    status, basis_l, xb_l = status[:bsz], basis_out[:bsz, :m], xb_out[:bsz, :m]
-    # Objective OUTSIDE the kernel from the exact terminal (basis, xb):
-    # a multi-term reduction lowered inside the kernel may reassociate
-    # differently from the XLA driver's — this way both backends return
-    # the same floats (see revised_pallas.py).
-    cb2 = revised._basic_costs(
-        basis_l, jnp.full((bsz,), 2, jnp.int32), c, m, n
-    )
-    objective = jnp.where(
-        status == 1,
-        jnp.sum(cb2 * xb_l, axis=-1),
-        jnp.asarray(-jnp.inf, dtype),
-    )
+    status, basis_l, xb_l = status[:bsz, 0], basis_out[:bsz, :m], xb_out[:bsz, :m]
+    # Objective OUTSIDE the kernel, by the XLA driver's own function on
+    # the exact terminal (basis, xb) — see revised_pallas.py.
+    objective = revised.objective_value(basis_l, xb_l, status, c, m, n)
     sol = LPSolution(
         objective=objective,
         x=x[:bsz, :n],
         status=status,
-        iterations=iters[:bsz],
+        iterations=iters[:bsz, 0],
         basis=basis_l,
     )
     if not want_state:
@@ -692,7 +851,7 @@ def _revised_launch(a, b, c, state, cap, *, rule, seed, tol, tile_b,
         binv=binv_out[:bsz, :m, :m],
         basis=basis_l,
         xb=xb_l,
-        phase=phase_out[:bsz],
+        phase=phase_out[:bsz, 0],
     )
     return sol, out_state
 
@@ -739,6 +898,7 @@ def revised_compile_cache_size() -> int:
     return (
         int(_revised_solve_jit._cache_size())
         + int(_revised_resume_jit._cache_size())
+        + _split_cache_size((_revised_solve_jit, _revised_resume_jit))
     )
 
 
@@ -772,16 +932,18 @@ def revised_solve(
     if interpret is None:
         interpret = not _on_tpu()
     m, n = a.shape
-    bsz = b.shape[0]
+    split = _batch_split(b)
+    bsz = b.shape[0] // _shards(split)
     if tile_b is None:
         tile_b = revised_auto_tile_b(bsz, m, n, a.dtype)
+    tile_b = legal_tile_b(tile_b, bsz)
     cap = resolve_cap(max_iters, m, n)
     if tol <= 0.0:
         tol = engine.default_tolerance(a.dtype)
     static_cap = None if dynamic_cap else int(cap)
-    cap_arr = jnp.full((1,), cap if dynamic_cap else 0, jnp.int32)
-    return _revised_solve_jit(
-        a, b, c, basis0, cap_arr,
+    cap_arr = jnp.array([cap if dynamic_cap else 0, 0], jnp.int32)
+    return _launch_split(
+        _revised_solve_jit, split, cap_arr, (a,), (b, c, basis0),
         rule=rule, seed=seed, tol=tol, tile_b=tile_b,
         static_cap=static_cap, want_state=want_state, interpret=interpret,
     )
@@ -812,16 +974,18 @@ def revised_resume(
     if interpret is None:
         interpret = not _on_tpu()
     m, n = a.shape
-    bsz = b.shape[0]
+    split = _batch_split(b)
+    bsz = b.shape[0] // _shards(split)
     if tile_b is None:
         tile_b = revised_auto_tile_b(bsz, m, n, a.dtype)
+    tile_b = legal_tile_b(tile_b, bsz)
     cap = resolve_cap(max_iters, m, n)
     if tol <= 0.0:
         tol = engine.default_tolerance(a.dtype)
     static_cap = None if dynamic_cap else int(cap)
-    cap_arr = jnp.full((1,), cap if dynamic_cap else 0, jnp.int32)
-    return _revised_resume_jit(
-        a, b, c, state, cap_arr,
+    cap_arr = jnp.array([cap if dynamic_cap else 0, 0], jnp.int32)
+    return _launch_split(
+        _revised_resume_jit, split, cap_arr, (a,), (b, c, state),
         rule=rule, seed=seed, tol=tol, tile_b=tile_b,
         static_cap=static_cap, want_state=want_state, interpret=interpret,
     )
@@ -832,23 +996,30 @@ def hyperbox_support(
     lo: jnp.ndarray,
     hi: jnp.ndarray,
     directions: jnp.ndarray,
-    tile_b: int = 256,
+    tile_b: int = 8192,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Box support values via the streaming Pallas kernel. (B, n) -> (B,)."""
+    """Box support values via the streaming Pallas kernel. (B, n) -> (B,).
+
+    The kernel streams the batch along lanes (see ``hyperbox_pallas.py``):
+    ``lo``/``hi``/``directions`` are broadcast to (B, n), transposed to
+    (n, B) and zero-padded to 8 sublanes and to a whole number of tiles of
+    ``tile_b`` directions (rounded to a multiple of 128, or the whole
+    128-padded batch when that is smaller).
+    """
     if interpret is None:
         interpret = not _on_tpu()
     bsz, n = directions.shape
-    lo = jnp.broadcast_to(lo, directions.shape)
-    hi = jnp.broadcast_to(hi, directions.shape)
-    np_pad = _round_up(n, 128)
-    tile = min(tile_b, _round_up(bsz, 8))
+    tile = min(_round_up(tile_b, 128), _round_up(bsz, 128))
     bp = _round_up(bsz, tile)
+    np_pad = _round_up(n, 8)
 
-    def pad(x):
-        return jnp.zeros((bp, np_pad), x.dtype).at[:bsz, :n].set(x)
+    def pad_t(x):
+        x = jnp.broadcast_to(x, directions.shape).T
+        return jnp.zeros((np_pad, bp), x.dtype).at[:n, :bsz].set(x)
 
     out = hyperbox_pallas(
-        pad(lo), pad(hi), pad(directions), n=n, tile_b=tile, interpret=interpret
+        pad_t(lo), pad_t(hi), pad_t(directions), tile_b=tile,
+        vmem_limit_bytes=VMEM_BUDGET_BYTES, interpret=interpret,
     )
-    return out[:bsz]
+    return out[0, :bsz]

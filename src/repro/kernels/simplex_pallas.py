@@ -42,6 +42,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import engine
 from ..core.lp import ITER_LIMIT, RUNNING, UNBOUNDED
@@ -51,18 +52,18 @@ _BIG = engine.BIG
 
 
 def _kernel(
+    cap_ref,  # (2,) i32 SMEM — iteration cap (compile-once caps), first global row
     tab_ref,  # (TB, M1p, Qp) f32 VMEM — prebuilt tableau (padded)
     basis_ref,  # (TB, Mp) i32 VMEM
-    phase_ref,  # (TB,) i32 VMEM
+    phase_ref,  # (TB, 1) i32 VMEM
     cext_ref,  # (TB, Qp) f32 VMEM — phase-II costs
-    feas_ref,  # (TB,) f32 VMEM — per-LP phase-I feasibility threshold
-    cap_ref,  # (1,) i32 — iteration cap (scalar input: compile-once caps)
-    obj_ref,  # out (TB,) f32
+    feas_ref,  # (TB, 1) f32 VMEM — per-LP phase-I feasibility threshold
+    obj_ref,  # out (TB, 1) f32
     x_ref,  # out (TB, Np) f32
-    status_ref,  # out (TB,) i32
-    iters_ref,  # out (TB,) i32
+    status_ref,  # out (TB, 1) i32
+    iters_ref,  # out (TB, 1) i32
     basis_out_ref,  # out (TB, Mp) i32 — final basis (warm-start reuse)
-    *state_out_refs,  # want_state: out (TB, M1p, Qp) f32 tab, (TB,) i32 phase
+    *state_out_refs,  # want_state: out (TB, M1p, Qp) f32 tab, (TB, 1) i32 phase
     spec: TableauSpec,
     rule: str,
     seed: int,
@@ -71,21 +72,26 @@ def _kernel(
     want_state: bool,
 ):
     m, n = spec.m, spec.n
-    tb = tab_ref.shape[0]
-    qp = tab_ref.shape[2]
+    tb, _, qp = tab_ref.shape
+    mp = basis_ref.shape[1]
+    np_pad = x_ref.shape[1]
 
+    # Per-LP scalars enter as (TB, 1) and rows as (TB, K) blocks; the
+    # engine wants (TB, 1, 1) scalars, (TB, 1, K) rows and (TB, m, 1)
+    # columns (see core/engine.py), so reshape / re-orient once here.
     tab = tab_ref[...]
-    basis = basis_ref[...][:, :m]
-    phase = phase_ref[...]
-    c_ext = cext_ref[...]
-    feas_tol = feas_ref[...]
+    basis = engine.to_column(basis_ref[...].reshape(tb, 1, mp), gather=False)
+    basis = basis[:, :m, :]
+    phase = phase_ref[...].reshape(tb, 1, 1)
+    c_ext = cext_ref[...].reshape(tb, 1, qp)
+    feas_tol = feas_ref[...].reshape(tb, 1, 1)
     dtype = tab.dtype
     limit = static_cap if static_cap is not None else cap_ref[0]
 
     elig = engine.eligible_mask(qp, m, n)  # padded lanes never enter
     # Global row base of this tile: keys the RPC noise so the draw is
     # independent of the tiling (and bitwise-equal to the XLA driver's).
-    row0 = pl.program_id(0) * tb
+    row0 = cap_ref[1] + pl.program_id(0) * tb
 
     def body(state):
         tab, basis, phase, status, iters, step = state
@@ -96,7 +102,9 @@ def _kernel(
             if rule == engine.RPC
             else None
         )
-        e, max_c = engine.select_entering(tab[:, m, :], elig, rule, tol, noise)
+        e, max_c = engine.select_entering(
+            tab[:, m : m + 1, :], elig, rule, tol, noise
+        )
         at_opt = max_c <= tol
 
         tab, phase, status = engine.phase_transition(
@@ -105,9 +113,7 @@ def _kernel(
         )
 
         pivoting = active & ~at_opt
-        l, min_ratio, full_col = engine.ratio_test(
-            tab, basis, e, spec, tol, gather=False
-        )
+        l, min_ratio, full_col = engine.ratio_test(tab, basis, e, spec, tol)
         unbounded = pivoting & (min_ratio >= _BIG / 2)
         status = jnp.where(unbounded, UNBOUNDED, status)
         do_pivot = pivoting & ~unbounded
@@ -122,8 +128,8 @@ def _kernel(
         _, _, _, status, _, step = state
         return jnp.logical_and(step < limit, jnp.any(status == RUNNING))
 
-    status0 = jnp.full((tb,), RUNNING, jnp.int32)
-    iters0 = jnp.zeros((tb,), jnp.int32)
+    status0 = jnp.full((tb, 1, 1), RUNNING, jnp.int32)
+    iters0 = jnp.zeros((tb, 1, 1), jnp.int32)
     tab, basis, phase, status, iters, _ = jax.lax.while_loop(
         cond, body, (tab, basis, phase, status0, iters0, jnp.int32(0))
     )
@@ -132,32 +138,27 @@ def _kernel(
     # Finite sentinel instead of -inf inside the kernel; the wrapper
     # (kernels/ops.py) re-masks non-optimal objectives to -inf outside.
     objective, x = engine.extract_solution(
-        tab, basis, status, spec, x_ref.shape[1], fill=-_BIG
+        tab, basis, status, spec, np_pad, fill=-_BIG
     )
 
-    obj_ref[...] = objective
-    x_ref[...] = x
-    status_ref[...] = status
-    iters_ref[...] = iters
-    # Static-slice stores: .at[...].set on a value would materialize an
-    # index constant the Pallas tracer refuses to capture.
-    mp = basis_out_ref.shape[1]
-    if mp > m:
-        basis_out_ref[:, m:] = jnp.zeros((tb, mp - m), jnp.int32)
-    basis_out_ref[:, :m] = basis
+    obj_ref[...] = objective.reshape(tb, 1)
+    x_ref[...] = x.reshape(tb, np_pad)
+    status_ref[...] = status.reshape(tb, 1)
+    iters_ref[...] = iters.reshape(tb, 1)
+    basis_out_ref[...] = engine.to_row(basis, mp, gather=False).reshape(tb, mp)
     if want_state:
         tab_out_ref, phase_out_ref = state_out_refs
         tab_out_ref[...] = tab
-        phase_out_ref[...] = phase
+        phase_out_ref[...] = phase.reshape(tb, 1)
 
 
 def simplex_pallas(
     tab: jnp.ndarray,  # (B, M1p, Qp) padded tableau
     basis: jnp.ndarray,  # (B, Mp) int32 padded
-    phase: jnp.ndarray,  # (B,) int32
+    phase: jnp.ndarray,  # (B, 1) int32
     c_ext: jnp.ndarray,  # (B, Qp)
-    feas_tol: jnp.ndarray,  # (B,) phase-I feasibility threshold
-    cap: jnp.ndarray,  # (1,) int32 iteration cap (traced scalar input)
+    feas_tol: jnp.ndarray,  # (B, 1) phase-I feasibility threshold
+    cap: jnp.ndarray,  # (2,) int32 iteration cap (traced), first global row
     *,
     spec: TableauSpec,
     n_padded: int,
@@ -167,22 +168,31 @@ def simplex_pallas(
     tol: float = 1e-5,
     static_cap: Optional[int] = None,
     want_state: bool = False,
+    vmem_limit_bytes: int,
     interpret: bool = False,
 ):
     """Launch the VMEM-resident simplex kernel over batch tiles.
 
-    ``cap`` rides in as a (1,) scalar input shared by every tile;
+    ``cap`` rides in SMEM as a (2,) scalar input shared by every tile —
+    the iteration cap, then the batch's first global row (keying the RPC
+    noise when a sharded batch launches per device);
     ``static_cap`` (a trace-time int) overrides it for the cap-specialized
     baseline.  With ``want_state`` the kernel also writes the terminal
     tableau and phase (padded) so a capped round can be resumed exactly.
     ``spec`` (static) fixes the tableau layout the padded blocks carry.
+    Per-LP scalars travel as (B, 1) columns, so every block is 2-D or 3-D
+    with a sublane extent Mosaic accepts when ``tile_b`` is a multiple of
+    8 or the whole batch (``kernels/ops.py:auto_tile_b`` guarantees it).
+    ``vmem_limit_bytes`` is the scoped-VMEM limit Mosaic compiles the
+    kernel under — the same budget the tile rules planned against.
 
     A ``tile_b`` larger than the (padded) batch is clamped down to it —
-    a small batch runs as one small tile instead of crashing (the old
-    ``assert bsz % tile_b == 0``) or being padded up to a full tile.  A
-    batch that is not a tile multiple is a caller bug and still raises.
+    a small batch runs as one small tile instead of being padded up to a
+    full-size tile.  A batch that is not a tile multiple is a caller bug
+    and raises.
     """
     bsz, m1p, qp = tab.shape
+    mp = basis.shape[1]
     tile_b = min(tile_b, bsz)
     if bsz % tile_b != 0:
         raise ValueError(
@@ -200,41 +210,46 @@ def simplex_pallas(
         static_cap=static_cap,
         want_state=want_state,
     )
+    per_lp = pl.BlockSpec((tile_b, 1), lambda i: (i, 0))
+    # The tableau block is single-buffered: one tile's solve is long
+    # enough that prefetching the next tile would hide nothing, while a
+    # second buffer would cost as much VMEM as the tableau itself.
+    tab_block = pl.BlockSpec(
+        (tile_b, m1p, qp), lambda i: (i, 0, 0), pipeline_mode=pl.Buffered(1)
+    )
     out_specs = [
-        pl.BlockSpec((tile_b,), lambda i: (i,)),
+        per_lp,
         pl.BlockSpec((tile_b, n_padded), lambda i: (i, 0)),
-        pl.BlockSpec((tile_b,), lambda i: (i,)),
-        pl.BlockSpec((tile_b,), lambda i: (i,)),
-        pl.BlockSpec((tile_b, basis.shape[1]), lambda i: (i, 0)),
+        per_lp,
+        per_lp,
+        pl.BlockSpec((tile_b, mp), lambda i: (i, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((bsz,), tab.dtype),
+        jax.ShapeDtypeStruct((bsz, 1), tab.dtype),
         jax.ShapeDtypeStruct((bsz, n_padded), tab.dtype),
-        jax.ShapeDtypeStruct((bsz,), jnp.int32),
-        jax.ShapeDtypeStruct((bsz,), jnp.int32),
-        jax.ShapeDtypeStruct((bsz, basis.shape[1]), jnp.int32),
+        jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
+        jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
+        jax.ShapeDtypeStruct((bsz, mp), jnp.int32),
     ]
     if want_state:
-        out_specs += [
-            pl.BlockSpec((tile_b, m1p, qp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile_b,), lambda i: (i,)),
-        ]
+        out_specs += [tab_block, per_lp]
         out_shape += [
             jax.ShapeDtypeStruct((bsz, m1p, qp), tab.dtype),
-            jax.ShapeDtypeStruct((bsz,), jnp.int32),
+            jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
         ]
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_b, m1p, qp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile_b, basis.shape[1]), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b,), lambda i: (i,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            tab_block,
+            pl.BlockSpec((tile_b, mp), lambda i: (i, 0)),
+            per_lp,
             pl.BlockSpec((tile_b, qp), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            per_lp,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(tab, basis, phase, c_ext, feas_tol, cap)
+    )(cap, tab, basis, phase, c_ext, feas_tol)

@@ -16,9 +16,3 @@ def make_local_mesh(model: int = 1):
     n = jax.device_count()
     assert n % model == 0, (n, model)
     return jax.make_mesh((n // model, model), ("data", "model"))
-
-
-# Hardware constants for roofline terms (TPU v5e).
-PEAK_FLOPS_BF16 = 197e12  # per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link

@@ -27,6 +27,7 @@ from .. import api
 from ..core import lp as lp_mod
 from ..core.backends import SolveOptions
 from ..core.problem import LPProblem
+from ..runtime import compile_cache
 from ..runtime.straggler import run_with_speculation
 
 
@@ -53,6 +54,7 @@ def main():
                     choices=["xla", "pallas", "reference"])
     ap.add_argument("--inject-straggler", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     rng = np.random.default_rng(0)
     options = SolveOptions(rule=args.rule, backend=args.backend)

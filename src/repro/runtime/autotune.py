@@ -275,8 +275,8 @@ def _tile_candidates(
         top = kernel_ops.revised_auto_tile_b(batch, m, n, dtype)
     else:
         return [None]
-    tiles = sorted({max(1, top), max(1, top // 2), max(1, top // 4)}, reverse=True)
-    return list(tiles)
+    tiles = {kernel_ops.legal_tile_b(max(1, top // k), batch) for k in (1, 2, 4)}
+    return sorted(tiles, reverse=True)
 
 
 def candidate_configs(

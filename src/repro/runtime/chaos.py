@@ -51,6 +51,29 @@ class ShardCrash(ChaosError):
 #: retried: re-dispatching the same arguments cannot fix a bad argument.
 NON_TRANSIENT = (ValueError, TypeError, KeyError, NotImplementedError)
 
+#: Pallas's own lowering and verification exceptions, matched by class
+#: name because JAX defines them in private modules.
+_LOWERING_ERRORS = frozenset({"LoweringException", "MosaicError", "VerificationError"})
+
+#: What the TPU compilers say when they refuse a program.  The runtime
+#: raises these as the same ``JaxRuntimeError`` a device fault raises,
+#: so only the message tells them apart.
+_COMPILE_MARKERS = ("Mosaic failed to compile", "compile permanent error")
+
+
+def is_compile_error(exc: BaseException) -> bool:
+    """Whether ``exc`` is a kernel lowering or program compile failure.
+
+    Deterministic: the same program fails the same way on every retry,
+    and a retry on another backend would only hide that this one cannot
+    run on the device.
+    """
+    if any(t.__name__ in _LOWERING_ERRORS for t in type(exc).__mro__):
+        return True
+    return isinstance(exc, jax.errors.JaxRuntimeError) and any(
+        marker in str(exc) for marker in _COMPILE_MARKERS
+    )
+
 
 def is_transient(exc: BaseException) -> bool:
     """Whether a dispatch failure is worth a retry-from-carried-state.
@@ -58,10 +81,11 @@ def is_transient(exc: BaseException) -> bool:
     Injected faults (:class:`ChaosError`) and runtime/device errors are
     transient — the round's inputs are intact, so re-dispatching the same
     carried state can succeed.  :data:`NON_TRANSIENT` types (bad
-    arguments, unknown keys) are deterministic programming errors and
-    propagate immediately.
+    arguments, unknown keys) are deterministic programming errors, and
+    compile failures (:func:`is_compile_error`) deterministic refusals of
+    the program; both propagate immediately.
     """
-    return not isinstance(exc, NON_TRANSIENT)
+    return not isinstance(exc, NON_TRANSIENT) and not is_compile_error(exc)
 
 
 @dataclasses.dataclass

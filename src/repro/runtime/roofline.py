@@ -18,10 +18,12 @@ iteration cost model for each storage layout —
   state.  Intensity grows with ``tile_b`` — the only backend whose
   roofline position the batch size can move.
 
-Reference machine balance uses TPU v5e-class constants (197 TF/s peak,
-819 GB/s HBM => ~241 flop/byte); every layout sits far below it, so the
-roofline fraction column is ``intensity / balance`` — the ceiling on
-attainable peak-FLOP utilization.
+Peaks come from one table keyed by ``device_kind`` (:data:`PEAKS`); a
+device kind without published peaks is an error, never a default.  The
+v5e's 197 TFLOP/s is its bf16 matrix-unit peak, while every layout's
+iteration is float32 elementwise work on the vector unit — so the
+roofline fraction column, ``intensity / balance``, is a ceiling on
+matrix-unit utilization, not a prediction of attainable speed.
 
 This model lives in the library (not under ``benchmarks/``) because it
 is the static feature source of the cost-model autotuner
@@ -34,11 +36,39 @@ the intensity column of ``BENCH_memory.json``.
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, NamedTuple
 
-#: Reference accelerator for the machine-balance line (per chip, f32-ish).
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+
+class Peaks(NamedTuple):
+    """Published per-chip peaks."""
+
+    bf16_flops: float  # FLOP/s of the matrix unit in bfloat16
+    hbm_bytes_per_s: float
+    hbm_bytes: float
+
+
+#: Published peaks keyed by ``jax.Device.device_kind``.  Source: Google
+#: Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB of HBM at
+#: 819 GB/s per chip).
+PEAKS = {"TPU v5 lite": Peaks(bf16_flops=197e12, hbm_bytes_per_s=819e9, hbm_bytes=16e9)}
+
+#: The chip the cost model ranks candidates for, on any host.
+REFERENCE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them to "
+            "runtime/roofline.py:PEAKS with their source"
+        ) from None
+
+
+PEAK_FLOPS = peaks(REFERENCE_KIND).bf16_flops
+HBM_BW = peaks(REFERENCE_KIND).hbm_bytes_per_s
 MACHINE_BALANCE = PEAK_FLOPS / HBM_BW
 
 SIZES = (5, 28, 100, 200, 500)
@@ -47,14 +77,20 @@ KINDS = ("dense", "compact", "pdhg", "shared")
 
 
 def iteration_profile(
-    kind: str, m: int, n: int, tile_b: int = 1, dtype_bytes: int = 4
+    kind: str,
+    m: int,
+    n: int,
+    tile_b: int = 1,
+    dtype_bytes: int = 4,
+    device_kind: str = REFERENCE_KIND,
 ) -> Dict[str, float]:
     """FLOPs / HBM bytes / intensity for ONE lockstep iteration of one LP.
 
     ``tile_b`` only matters for ``kind="shared"``: the shared ``A`` block
     is fetched once per tile, so its bytes are divided by the tile size.
     Byte counts are steady-state HBM traffic (state read + written each
-    iteration); FLOPs count multiply-adds as 2.
+    iteration); FLOPs count multiply-adds as 2.  ``roofline_fraction``
+    is against ``device_kind``'s peaks (:func:`peaks`).
     """
     if kind in ("dense", "compact"):
         q = 1 + n + (2 * m if kind == "dense" else m)
@@ -75,11 +111,12 @@ def iteration_profile(
     else:
         raise ValueError(f"unknown kind {kind!r}; expected one of {KINDS}")
     ai = flops / byts
+    chip = peaks(device_kind)
     return {
         "flops": flops,
         "bytes": byts,
         "intensity": ai,
-        "roofline_fraction": ai / MACHINE_BALANCE,
+        "roofline_fraction": ai / (chip.bf16_flops / chip.hbm_bytes_per_s),
     }
 
 

@@ -514,7 +514,8 @@ class LPEngine:
         through the dead-letter path — its tickets complete with
         ``NUMERICAL`` status and a NaN objective — while every other
         group keeps advancing.  Non-transient errors (``ValueError`` and
-        friends: caller bugs, not infrastructure faults) propagate.
+        friends: caller bugs; kernel compile failures: the program cannot
+        run here) propagate.
         """
         for key in list(self._groups):
             g = self._groups[key]

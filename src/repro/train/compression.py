@@ -70,7 +70,6 @@ def dp_allreduce_int8(grads, mesh, axis: str = "data"):
     Used by the distributed test (8 host devices) to verify wire-format
     correctness against the fp32 psum within EF tolerance.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def reduce_leaf(g):
@@ -83,7 +82,7 @@ def dp_allreduce_int8(grads, mesh, axis: str = "data"):
             n = jax.lax.psum(jnp.ones((), jnp.float32), axis)
             return summed.astype(jnp.float32) * scale / n
 
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
         )(g)
 

@@ -315,14 +315,14 @@ def test_cached_tile_b_overrides_auto_tile_heuristic(
     monkeypatch.setattr(kernel_ops, "_on_tpu", lambda: True)
     spec = TableauSpec(6, 5, "compact")
     heuristic = kernel_ops.auto_tile_b(64, spec, F32, want_state=True)
-    assert heuristic != 2  # the pinned value below must be distinguishable
+    assert heuristic != 16  # the pinned value below must be distinguishable
     key = autotune.cache_key(6, 5, 64, F32)
     autotune.TuningCache(isolated_tuner).store(
         key,
         {
             "backend": "pallas",
             "layout": "compact",
-            "tile_b": 2,
+            "tile_b": 16,
             "measured_s": 1e-4,
             "m_class": 8,
             "n_class": 8,
@@ -332,11 +332,11 @@ def test_cached_tile_b_overrides_auto_tile_heuristic(
         },
     )
     autotune.reset(cache_path=isolated_tuner)
-    assert autotune.cached_tile_b(64, 6, 5, F32, "compact") == 2
-    assert kernel_ops.auto_tile_b(64, spec, F32, want_state=True) == 2
+    assert autotune.cached_tile_b(64, 6, 5, F32, "compact") == 16
+    assert kernel_ops.auto_tile_b(64, spec, F32, want_state=True) == 16
     # predicted-only entries (no measured_s) never pin a tile
     autotune.TuningCache(isolated_tuner).store(
-        key, {"backend": "pallas", "layout": "compact", "tile_b": 2,
+        key, {"backend": "pallas", "layout": "compact", "tile_b": 16,
               "measured_s": None, "m_class": 8, "n_class": 8,
               "batch_class": 64, "dtype": "float32", "shared": False},
     )
@@ -371,3 +371,14 @@ def test_warn_once_table_is_bounded_and_resettable():
     with pytest.warns(UserWarning, match="re-armed"):
         backends._warn_once(live_key, "re-armed")
     backends.reset_warnings()
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    from repro.runtime import roofline
+
+    v5e = roofline.peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bytes_per_s, v5e.hbm_bytes) == (197e12, 819e9, 16e9)
+    share = roofline.iteration_profile("compact", 28, 28)["roofline_fraction"]
+    assert 0.0 < share < 1.0
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.iteration_profile("compact", 28, 28, device_kind="cpu")

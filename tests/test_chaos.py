@@ -16,6 +16,7 @@ numerical guardrails:
   sees it, naming the offending field.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -104,6 +105,70 @@ def test_non_transient_errors_are_not_retried():
             _batch(), SolveOptions(backend="no-such-backend"), None, (), stats
         )
     assert stats.retries == 0
+
+
+class LoweringException(Exception):
+    """Stands in for Pallas's lowering exception (matched by class name)."""
+
+
+#: Compile-time refusals as the TPU stack raises them, and a runtime fault.
+MOSAIC_REFUSAL = jax.errors.JaxRuntimeError(
+    "INTERNAL: Mosaic failed to compile TPU kernel: Not implemented: Lane broadcast"
+)
+HBM_REFUSAL = jax.errors.JaxRuntimeError(
+    "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of memory "
+    "in memory space hbm."
+)
+
+
+def test_compile_errors_are_not_transient():
+    for exc in (MOSAIC_REFUSAL, HBM_REFUSAL, LoweringException("no rule")):
+        assert chaos.is_compile_error(exc)
+        assert not chaos.is_transient(exc)
+    device_fault = jax.errors.JaxRuntimeError("UNAVAILABLE: TPU device lost")
+    assert not chaos.is_compile_error(device_fault)
+    assert chaos.is_transient(device_fault)
+
+
+@pytest.mark.parametrize(
+    "error,retried",
+    [
+        (MOSAIC_REFUSAL, False),
+        (LoweringException("no lowering rule"), False),
+        (NotImplementedError("no lowering rule"), False),
+        (chaos.ChaosError("injected"), True),
+    ],
+)
+def test_pallas_round_retries_only_runtime_faults(monkeypatch, recwarn, error, retried):
+    """A kernel compile error surfaces; only a runtime fault retries on xla."""
+    from repro.core import backends
+    from repro.kernels import ops
+
+    batch = _batch()
+    opts = SolveOptions(backend="pallas", retry_backoff=0.0)
+    ref = dispatch.solve_canonical(batch, opts)
+    real = ops.simplex_solve
+    calls = []
+
+    def failing_once(*args, **kw):
+        calls.append(1)
+        if len(calls) == 1:
+            raise error
+        return real(*args, **kw)
+
+    monkeypatch.setattr(ops, "simplex_solve", failing_once)
+    backends.reset_warnings()
+    stats = SolveStats()
+    if not retried:
+        with pytest.raises(type(error)):
+            dispatch.solve_canonical(batch, opts, stats=stats)
+        assert stats.retries == 0
+        assert not [w for w in recwarn if "dispatch fault" in str(w.message)]
+        return
+    sol = dispatch.solve_canonical(batch, opts, stats=stats)
+    assert stats.retries == 1
+    assert [w for w in recwarn if "dispatch fault" in str(w.message)]
+    _assert_identical(ref, sol)
 
 
 def test_shard_crash_mid_round_recovers_bit_identical():

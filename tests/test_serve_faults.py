@@ -158,3 +158,28 @@ def test_dead_letter_keeps_engine_serviceable():
     t = eng.submit(_problem(4, 6, 99))
     sol = eng.result(t)
     assert int(sol.status[0]) == OPTIMAL
+
+
+def test_engine_raises_on_kernel_compile_error(monkeypatch):
+    """A kernel the compiler refuses propagates out of step(): no dead letters."""
+    import jax
+
+    from repro.kernels import ops
+
+    def refuse(*args, **kw):
+        raise jax.errors.JaxRuntimeError(
+            "INTERNAL: Mosaic failed to compile TPU kernel: Not implemented"
+        )
+
+    monkeypatch.setattr(ops, "simplex_solve", refuse)
+    monkeypatch.setattr(ops, "simplex_resume", refuse)
+    eng = LPEngine(
+        SolveOptions(backend="pallas", retry_backoff=0.0),
+        flush_every=10**9, step_iters=8,
+    )
+    eng.submit(_problem(4, 6, 0))
+    with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic failed to compile"):
+        for _ in range(10):
+            eng.step()
+    assert eng.dead_letters == []
+    assert eng.stats.dead_lettered == 0

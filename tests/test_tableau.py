@@ -292,8 +292,8 @@ def test_pallas_resume_routes_on_state_layout(monkeypatch):
     compact_lp = ops.kernel_vmem_bytes_per_lp(
         TableauSpec(b.m, b.n, "compact"), np.float32, want_state=True
     )
-    # A budget that admits compact but not dense.
-    budget = int((dense_lp + compact_lp) / 2 / ops.VMEM_TILE_FRACTION)
+    # A budget that admits a smallest legal tile of compact but not dense.
+    budget = int(ops.MIN_TILE_B * (dense_lp + compact_lp) / 2 / ops.VMEM_TILE_FRACTION)
     monkeypatch.setattr(ops, "VMEM_BUDGET_BYTES", budget)
     assert ops.fits_vmem(b.m, b.n, layout="compact", want_state=True)
     assert not ops.fits_vmem(b.m, b.n, layout="dense", want_state=True)

@@ -39,6 +39,7 @@ MODULES = [
     ("repro.runtime.roofline", "Analytic roofline model"),
     ("repro.runtime.chaos", "Fault injection (chaos testing)"),
     ("repro.runtime.straggler", "Straggler-speculation scheduler"),
+    ("repro.runtime.compile_cache", "Persistent compilation cache"),
 ]
 
 HEADER = """# API reference
