@@ -45,6 +45,7 @@ from .core.backends import SolveOptions, SolveStats
 from .core.bucketing import ShapeGrid, bucket_problems, scatter_solutions
 from .core.lp import INFEASIBLE, LPBatch, LPSolution, SharedLPBatch
 from .core.problem import LPProblem, canonicalize, solve_box, uncanonicalize
+from .runtime import trace as _trace
 
 Solvable = Union[LPProblem, LPBatch, SharedLPBatch, Sequence[LPProblem]]
 
@@ -95,15 +96,24 @@ def solve(
     ------
     TypeError
         For any other input type.
+
+    Notes
+    -----
+    Under ``jax.profiler`` the call is one ``repro.solve`` span, with
+    the front end's and the dispatch's spans inside it
+    (``repro.runtime.trace``).
     """
     if isinstance(problem, (LPBatch, SharedLPBatch)):
-        return _dispatch.solve_canonical(
-            problem, options, mesh=mesh, batch_axes=batch_axes, stats=stats
-        )
+        with _trace.span("solve", kind="batch"):
+            return _dispatch.solve_canonical(
+                problem, options, mesh=mesh, batch_axes=batch_axes, stats=stats
+            )
     if isinstance(problem, LPProblem):
-        return _solve_problem(problem, options, mesh, batch_axes, stats)
+        with _trace.span("solve", kind="problem"):
+            return _solve_problem(problem, options, mesh, batch_axes, stats)
     if isinstance(problem, (list, tuple)):
-        return _solve_many(problem, options, mesh, batch_axes, grid, stats)
+        with _trace.span("solve", kind="list"):
+            return _solve_many(problem, options, mesh, batch_axes, grid, stats)
     raise TypeError(
         f"repro.solve expects LPProblem, LPBatch, SharedLPBatch, or a "
         f"list of LPProblem; got {type(problem).__name__}"
@@ -166,7 +176,8 @@ def _solve_problem(
                 stats.record(sol)
             return sol
         return _solve_box_via_backend(problem, options, mesh, batch_axes, stats)
-    canon = canonicalize(problem)
+    with _trace.span("frontend.canonicalize", rows=problem.batch):
+        canon = canonicalize(problem)
     sol = _dispatch.solve_canonical(
         canon.batch, options, mesh=mesh, batch_axes=batch_axes, stats=stats
     )
@@ -213,9 +224,11 @@ def _solve_many(
 ) -> List[LPSolution]:
     if not problems:
         return []
-    buckets = bucket_problems(problems, grid)
+    with _trace.span("frontend.bucket", rows=len(problems)):
+        buckets = bucket_problems(problems, grid)
     sols = [
         _solve_problem(b.problem, options, mesh, batch_axes, stats)
         for b in buckets
     ]
-    return scatter_solutions(buckets, sols, len(problems))
+    with _trace.span("frontend.scatter", rows=len(problems)):
+        return scatter_solutions(buckets, sols, len(problems))

@@ -45,6 +45,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import trace as _trace
 from . import engine as _engine
 from . import hyperbox as _hyperbox
 from . import pdhg as _pdhg
@@ -409,8 +410,16 @@ class SolveStats:
     Pass an instance to :func:`repro.solve` /
     :func:`repro.core.dispatch.solve_canonical` (``stats=``) to measure
     the work a pipeline actually performed — the counters that make the
-    compaction and warm-start wins observable.  Recording forces a device
-    sync per backend call, so it is opt-in (``stats=None`` costs nothing).
+    compaction and warm-start wins observable.  It is opt-in
+    (``stats=None`` costs nothing).
+
+    Only :meth:`record` forces a sync: it reads each dispatched chunk's
+    iteration counts back to the host, and it alone feeds ``lps``,
+    ``rounds``, ``simplex_iterations`` and ``lockstep_iterations``.  So a
+    solve with ``stats=`` waits for each chunk before dispatching the next.
+    Every other counter is host bookkeeping the pipeline already holds,
+    and ``host_syncs`` counts the syncs themselves, ``record``'s own
+    included.
 
     Attributes
     ----------
@@ -487,6 +496,16 @@ class SolveStats:
         ``measured_s`` cost, and the decision ``source``
         (``"predicted"``/``"measured"``/``"cache"``) — the
         predicted-versus-measured audit trail.
+    host_syncs : int
+        Host read-backs the pipeline made (each a ``dispatch.sync`` span,
+        :func:`read_back`): the per-round status read of a compaction or
+        two-pass solve, :meth:`record`'s reads, the quarantine lane's
+        reads, the speculative chunk waits, and the serve loop's
+        per-round reads.  A plain one-round solve without ``stats=``
+        makes none.
+    bytes_staged : int
+        Host bytes handed to ``jax.device_put`` by the chunk staging
+        (each a ``dispatch.stage`` span).
     """
 
     lps: int = 0
@@ -505,6 +524,8 @@ class SolveStats:
     faults_injected: int = 0
     autotuned: int = 0
     autotune_log: List[dict] = dataclasses.field(default_factory=list)
+    host_syncs: int = 0
+    bytes_staged: int = 0
 
     def record_tableau(self, nbytes: int) -> None:
         """Fold one dispatch round's tableau footprint into the peak.
@@ -538,13 +559,27 @@ class SolveStats:
         sol : LPSolution
             The solution batch returned by a backend dispatch.
         """
-        iters = np.asarray(sol.iterations)
+        iters = read_back(sol.iterations, "stats.record", self)
         if iters.size == 0:
             return
         self.lps += int(iters.size)
         self.rounds += 1
         self.simplex_iterations += int(iters.sum())
         self.lockstep_iterations += int(iters.max()) * int(iters.size)
+
+
+def read_back(x, site: str, stats: Optional[SolveStats] = None) -> np.ndarray:
+    """Copy ``x`` to the host: one host sync, spanned and counted.
+
+    The pipeline's read-backs of one array go through here, so a traced
+    run shows each as a ``dispatch.sync`` span (``site`` names the
+    caller) and ``stats.host_syncs`` counts it.
+    """
+    with _trace.span("dispatch.sync", site=site):
+        out = np.asarray(x)
+    if stats is not None:
+        stats.host_syncs += 1
+    return out
 
 
 @dataclasses.dataclass(frozen=True)

@@ -83,6 +83,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..runtime import chaos as _chaos
+from ..runtime import trace as _trace
 from . import pdhg as _pdhg
 from . import revised as _revised
 from .backends import (
@@ -92,6 +93,7 @@ from .backends import (
     SolveStats,
     fault_fallback,
     get_backend,
+    read_back,
     route_shape,
 )
 from .bucketing import next_pow2
@@ -151,21 +153,23 @@ def _trim_solution(sol: LPSolution, k: int) -> LPSolution:
 def _concat_solutions(parts: Sequence[LPSolution]) -> LPSolution:
     bases = [p.basis for p in parts]
     ys = [p.y for p in parts]
-    return LPSolution(
-        objective=jnp.concatenate([p.objective for p in parts]),
-        x=jnp.concatenate([p.x for p in parts]),
-        status=jnp.concatenate([p.status for p in parts]),
-        iterations=jnp.concatenate([p.iterations for p in parts]),
-        basis=jnp.concatenate(bases) if all(b is not None for b in bases) else None,
-        y=jnp.concatenate(ys) if all(y is not None for y in ys) else None,
-    )
+    with _trace.span("dispatch.concat"):
+        return LPSolution(
+            objective=jnp.concatenate([p.objective for p in parts]),
+            x=jnp.concatenate([p.x for p in parts]),
+            status=jnp.concatenate([p.status for p in parts]),
+            iterations=jnp.concatenate([p.iterations for p in parts]),
+            basis=jnp.concatenate(bases) if all(b is not None for b in bases) else None,
+            y=jnp.concatenate(ys) if all(y is not None for y in ys) else None,
+        )
 
 
 def _concat_states(parts: Sequence):
     # Any resume-state flavor (simplex ResumeState, PDHGResumeState, a
     # plug-in backend's record): both are registered dataclass pytrees,
     # so leaf-wise concatenation rebuilds the same record type.
-    return jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *parts)
+    with _trace.span("dispatch.concat"):
+        return jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *parts)
 
 
 def _resolve_axes(
@@ -411,7 +415,18 @@ def resolve_backend(
     — the caller asked for a simplex driver and the revised engine IS
     the simplex driver for this container.  ``pdhg``/``reference``
     pass through (the caller densifies for them).
+
+    The call is one ``dispatch.resolve`` span, autotuning included; its
+    ``backend`` attribute is the resolved backend.
     """
+    with _trace.span("dispatch.resolve") as sp:
+        options = _resolve(m, n, dtype, options, shared, batch, stats)
+        sp.set(backend=options.backend)
+    return options
+
+
+def _resolve(m, n, dtype, options, shared, batch, stats) -> SolveOptions:
+    """:func:`resolve_backend` without its span."""
     name = options.backend
     if shared:
         if name == "xla":
@@ -636,7 +651,7 @@ def _quarantine_resolve(
     cannot finish stay ``NUMERICAL`` — a wrong certificate is never
     fabricated.
     """
-    status = np.asarray(sol.status)
+    status = read_back(sol.status, "quarantine", stats)
     flagged = np.nonzero(status == NUMERICAL)[0]
     if flagged.size == 0:
         return sol
@@ -645,9 +660,12 @@ def _quarantine_resolve(
     sub = _gather_batch(batch, jnp.asarray(flagged))
     if isinstance(sub, SharedLPBatch):
         sub = sub.densify()
-    a = np.asarray(sub.a, np.float64)
-    b = np.asarray(sub.b, np.float64)
-    c = np.asarray(sub.c, np.float64)
+    with _trace.span("dispatch.sync", site="quarantine.inputs"):
+        a = np.asarray(sub.a, np.float64)
+        b = np.asarray(sub.b, np.float64)
+        c = np.asarray(sub.c, np.float64)
+    if stats is not None:
+        stats.host_syncs += 1
     finite = (
         np.isfinite(a).all(axis=(1, 2))
         & np.isfinite(b).all(axis=1)
@@ -778,7 +796,8 @@ def solve_canonical(
             sub_state = None
             size_class = None
         else:
-            active = np.nonzero(np.asarray(sol.status) == ITER_LIMIT)[0]
+            status = read_back(sol.status, "round_status", stats)
+            active = np.nonzero(status == ITER_LIMIT)[0]
             if active.size == 0:
                 break
             idx = jnp.asarray(active)
@@ -793,16 +812,17 @@ def solve_canonical(
             else:
                 sub_state = None
             size_class = next_pow2(int(active.size))
-        part, part_state = dispatch_round_safe(
-            sub,
-            base.replace(max_iters=cap),
-            mesh,
-            batch_axes,
-            stats,
-            state=sub_state,
-            want_state=want_state,
-            size_class=size_class,
-        )
+        with _trace.span("dispatch.round", round=r, rows=sub.batch, cap=cap):
+            part, part_state = dispatch_round_safe(
+                sub,
+                base.replace(max_iters=cap),
+                mesh,
+                batch_axes,
+                stats,
+                state=sub_state,
+                want_state=want_state,
+                size_class=size_class,
+            )
         if options.guardrails:
             # Checked at the existing one-host-sync-per-round status
             # read-back below: a poisoned row retires NUMERICAL here and
@@ -930,11 +950,15 @@ def dispatch_round(
             if monkey is not None:
                 monkey.on_chunk(chaos_round, k)
             hi = min(lo + chunk, bsz)
-            cur = staged or _stage_round_inputs(batch, state, lo, hi, mesh, axes)
-            out, out_state = _solve_chunk(backend, cur, options, want_state, stats)
+            cur = staged or _stage_round_inputs(
+                batch, state, lo, hi, mesh, axes, k, stats
+            )
+            out, out_state = _solve_chunk(backend, cur, options, want_state, stats, k)
             nxt_lo, nxt_hi = hi, min(hi + chunk, bsz)
             staged = (
-                _stage_round_inputs(batch, state, nxt_lo, nxt_hi, mesh, axes)
+                _stage_round_inputs(
+                    batch, state, nxt_lo, nxt_hi, mesh, axes, k + 1, stats
+                )
                 if nxt_lo < bsz
                 else None
             )
@@ -1004,21 +1028,27 @@ def _speculative_chunks(
         k, (lo, hi) = payload
         if monkey is not None:
             monkey.on_chunk(chaos_round, k)
-        cur = _stage_round_inputs(batch, state, lo, hi, None, ())
-        out, out_state = _solve_chunk(backend, cur, options, want_state, None)
+        # Worker threads count into their own record, merged below.
+        counts = SolveStats()
+        cur = _stage_round_inputs(batch, state, lo, hi, None, (), k, counts)
+        out, out_state = _solve_chunk(backend, cur, options, want_state, None, k)
         # Block here so the scheduler's per-unit elapsed times measure
         # the solve, not the async dispatch — the straggler deadline
         # needs real durations.
-        jax.block_until_ready(out.status)
-        return out, out_state
+        with _trace.span("dispatch.sync", site="speculation"):
+            jax.block_until_ready(out.status)
+        counts.host_syncs += 1
+        return out, out_state, counts
 
     report = run_with_speculation(
         list(enumerate(ranges)), solve_unit, n_workers=min(4, len(ranges))
     )
     parts, state_parts = [], []
     for (lo, hi), unit in zip(ranges, report.results):
-        out, out_state = unit.value
+        out, out_state, counts = unit.value
         if stats is not None:
+            stats.bytes_staged += counts.bytes_staged
+            stats.host_syncs += counts.host_syncs
             valid = min(hi, true_bsz) - lo
             if valid > 0:
                 stats.record(out if valid == hi - lo else _trim_solution(out, valid))
@@ -1030,11 +1060,22 @@ def _speculative_chunks(
     return parts, state_parts
 
 
-def _stage_round_inputs(batch, state, lo, hi, mesh, axes):
-    return (
-        _stage_batch(batch, lo, hi, mesh, axes),
-        None if state is None else _stage_state(state, lo, hi, mesh, axes),
-    )
+def _stage_round_inputs(batch, state, lo, hi, mesh, axes, chunk, stats):
+    """Stage rows ``lo:hi`` (and their carried state): one ``dispatch.stage`` span.
+
+    The bytes handed to ``jax.device_put`` are the span's ``bytes`` and
+    add to ``stats.bytes_staged``.
+    """
+    with _trace.span("dispatch.stage", chunk=chunk) as sp:
+        cur = (
+            _stage_batch(batch, lo, hi, mesh, axes),
+            None if state is None else _stage_state(state, lo, hi, mesh, axes),
+        )
+        nbytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(cur))
+        sp.set(bytes=nbytes)
+    if stats is not None:
+        stats.bytes_staged += nbytes
+    return cur
 
 
 def _solve_chunk(
@@ -1043,16 +1084,19 @@ def _solve_chunk(
     options: SolveOptions,
     want_state: bool,
     stats: Optional[SolveStats],
+    chunk: int,
 ) -> Tuple[LPSolution, Optional[ResumeState]]:
-    """Run one chunk through the backend, attributing compiles vs hits."""
+    """Run one chunk through the backend (a ``dispatch.enqueue`` span),
+    attributing compiles vs hits."""
     cur_batch, cur_state = cur
     before = backend.cache_size() if stats is not None and backend.cache_size else None
-    if cur_state is not None:
-        out, out_state = backend.resume_canonical(cur_batch, cur_state, options)
-    elif want_state:
-        out, out_state = backend.start_canonical(cur_batch, options)
-    else:
-        out, out_state = backend.solve_canonical(cur_batch, options), None
+    with _trace.span("dispatch.enqueue", chunk=chunk):
+        if cur_state is not None:
+            out, out_state = backend.resume_canonical(cur_batch, cur_state, options)
+        elif want_state:
+            out, out_state = backend.start_canonical(cur_batch, options)
+        else:
+            out, out_state = backend.solve_canonical(cur_batch, options), None
     if before is not None:
         stats.record_cache(before, backend.cache_size())
     return out, out_state

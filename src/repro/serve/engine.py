@@ -37,7 +37,7 @@ import numpy as np
 
 from ..core import dispatch as _dispatch
 from ..core import pdhg as _pdhg
-from ..core.backends import SolveOptions, SolveStats, get_backend
+from ..core.backends import SolveOptions, SolveStats, get_backend, read_back
 from ..core.bucketing import ShapeGrid, next_pow2, shape_class
 from ..core.lp import ITER_LIMIT, NUMERICAL, LPBatch, LPSolution
 from ..core.problem import (
@@ -51,6 +51,7 @@ from ..core.problem import (
 from ..core.session import SolveSession
 from ..models.model import Model
 from ..runtime import chaos as _chaos
+from ..runtime import trace as _trace
 
 
 class Engine:
@@ -122,6 +123,12 @@ class _Group:
     remaining: List[int]  # per-row iteration budget left
     done: List[int]  # per-row iterations spent so far
     true_n: List[int]  # per-row unpadded variable count
+    label: str  # the shape class, as the group attribute of its spans
+
+
+def _group_label(key: Tuple) -> str:
+    """A group's shape class, ``"<m>x<n>"``, for the spans' ``group`` attribute."""
+    return f"{key[0]}x{key[1]}"
 
 
 class LPEngine:
@@ -205,6 +212,16 @@ class LPEngine:
         Time source ``() -> float`` that request deadlines are measured
         against (``deadline_misses`` counts completions past their
         deadline; injectable for tests).
+
+    Notes
+    -----
+    Under ``jax.profiler`` the engine writes ``serve.submit``,
+    ``serve.step``, ``serve.admit``, ``serve.advance`` and
+    ``serve.retire`` spans (``repro.runtime.trace``; ``ticket`` on
+    per-ticket spans, ``group`` on group spans), and each ticket that
+    completes appends two intervals timed on ``time.perf_counter``:
+    ``serve.queued`` (submit to admission) and ``serve.inflight``
+    (admission to completion).
     """
 
     def __init__(
@@ -250,6 +267,8 @@ class LPEngine:
         self._groups: Dict[Tuple, _Group] = {}
         self._next_ticket = 0
         self._step_count = 0
+        # ticket -> [submit, admit] on time.perf_counter, kept while traced
+        self._times: Dict[int, List[Optional[float]]] = {}
 
     @property
     def stats(self) -> SolveStats:
@@ -298,27 +317,31 @@ class LPEngine:
             everything past this point may assume admission-time data
             was finite.
         """
-        if isinstance(problem, LPProblem):
-            validate_problem(problem, where="submit: problem")
-        if deadline is not None:
-            deadline = float(deadline)
-            if np.isnan(deadline) or deadline < 0.0:
-                raise ValueError(
-                    "submit: deadline must be a non-negative clock time "
-                    f"(or None), got {deadline!r}"
-                )
-        ticket = self._next_ticket
-        self._next_ticket += 1
-        self._pending.append((ticket, problem))
-        self._pending_ids.add(ticket)
-        self._meta[ticket] = (
-            None if deadline is None else float(deadline),
-            int(priority),
-            self._step_count,
-        )
-        if len(self._pending) >= self.flush_every:
-            self.flush()
-        return ticket
+        with _trace.span("serve.submit") as sp:
+            if isinstance(problem, LPProblem):
+                validate_problem(problem, where="submit: problem")
+            if deadline is not None:
+                deadline = float(deadline)
+                if np.isnan(deadline) or deadline < 0.0:
+                    raise ValueError(
+                        "submit: deadline must be a non-negative clock time "
+                        f"(or None), got {deadline!r}"
+                    )
+            ticket = self._next_ticket
+            self._next_ticket += 1
+            sp.set(ticket=ticket)
+            if _trace.recording():
+                self._times[ticket] = [time.perf_counter(), None]
+            self._pending.append((ticket, problem))
+            self._pending_ids.add(ticket)
+            self._meta[ticket] = (
+                None if deadline is None else float(deadline),
+                int(priority),
+                self._step_count,
+            )
+            if len(self._pending) >= self.flush_every:
+                self.flush()
+            return ticket
 
     def done(self, ticket: int) -> bool:
         """Whether a ticket's result is ready to redeem."""
@@ -331,6 +354,7 @@ class LPEngine:
         self._pending = [(t, p) for t, p in self._pending if t != ticket]
         self._pending_ids.discard(ticket)
         self._meta.pop(ticket, None)
+        self._times.pop(ticket, None)
         return True
 
     # -- continuous scheduler -----------------------------------------------
@@ -344,8 +368,9 @@ class LPEngine:
         """
         self._step_count += 1
         completed: List[int] = []
-        self._admit(completed)
-        self._advance(completed)
+        with _trace.span("serve.step", step=self._step_count):
+            self._admit(completed)
+            self._advance(completed)
         return completed
 
     def _admit(self, completed: List[int]) -> None:
@@ -396,10 +421,19 @@ class LPEngine:
             probs.append(padded)
             true_ns.append(p.n)
         for key, (tickets, probs, true_ns) in waves.items():
-            self._admit_wave(key, tickets, probs, true_ns, completed)
+            self._mark_admitted(tickets)
+            with _trace.span("serve.admit", group=_group_label(key), rows=len(tickets)):
+                self._admit_wave(key, tickets, probs, true_ns, completed)
             wave = set(tickets)
             self._pending = [(t, p) for t, p in self._pending if t not in wave]
             self._pending_ids -= wave
+
+    def _mark_admitted(self, tickets: List[int]) -> None:
+        """Stamp the admission time of the tickets whose submit was traced."""
+        now = time.perf_counter()
+        for t in tickets:
+            if t in self._times:
+                self._times[t][1] = now
 
     def _admit_wave(
         self,
@@ -460,6 +494,7 @@ class LPEngine:
                 remaining=[],
                 done=[],
                 true_n=[],
+                label=_group_label(key),
             )
             self._groups[key] = g
         else:
@@ -521,7 +556,8 @@ class LPEngine:
             g = self._groups[key]
             if g.tickets:
                 try:
-                    self._step_group(g, completed)
+                    with _trace.span("serve.advance", group=g.label, rows=len(g.tickets)):
+                        self._step_group(g, completed)
                 except Exception as exc:
                     if not _chaos.is_transient(exc):
                         raise
@@ -593,13 +629,13 @@ class LPEngine:
                 sub, sub_state, int(v), g.options,
                 size_class=max(2, next_pow2(int(rows.size))),
             )
-            status[rows] = np.asarray(sol.status)
+            status[rows] = read_back(sol.status, "serve.status", self.stats)
             obj = obj.at[ridx].set(sol.objective)
             x = x.at[ridx].set(sol.x)
             new_state = jax.tree_util.tree_map(
                 lambda full, part: full.at[ridx].set(part), new_state, part_state
             )
-            done_inc[rows] = np.asarray(sol.iterations)
+            done_inc[rows] = read_back(sol.iterations, "serve.iterations", self.stats)
         # Every sub-dispatch succeeded: commit the round's bookkeeping.
         for i in range(nrows):
             g.done[i] += int(done_inc[i])
@@ -611,7 +647,8 @@ class LPEngine:
         kept = set(keep)
         drop = [i for i in range(nrows) if i not in kept]
         if drop:
-            self._retire(g, drop, status, obj, x, completed)
+            with _trace.span("serve.retire", group=g.label, rows=len(drop)):
+                self._retire(g, drop, status, obj, x, completed)
         if len(keep) == nrows:
             g.state = new_state
             return
@@ -679,6 +716,10 @@ class LPEngine:
         deadline, _, _ = self._meta.pop(ticket, (None, 0, 0))
         if deadline is not None and self.clock() > deadline:
             self.deadline_misses += 1
+        times = self._times.pop(ticket, None)
+        if times is not None and times[1] is not None:
+            _trace.interval("serve.queued", times[0], times[1], ticket=ticket)
+            _trace.interval("serve.inflight", times[1], time.perf_counter(), ticket=ticket)
         self._results[ticket] = sol
         self._inflight.pop(ticket, None)
         completed.append(ticket)
@@ -706,6 +747,7 @@ class LPEngine:
             return done
         tickets = [t for t, _ in self._pending]
         problems = [p for _, p in self._pending]
+        self._mark_admitted(tickets)
         sols = self.session.solve(problems)
         # Clear only after the solve succeeds: a raising solve (bad problem,
         # backend error) must not silently drop the other queued requests.

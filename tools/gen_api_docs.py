@@ -40,6 +40,7 @@ MODULES = [
     ("repro.runtime.chaos", "Fault injection (chaos testing)"),
     ("repro.runtime.straggler", "Straggler-speculation scheduler"),
     ("repro.runtime.compile_cache", "Persistent compilation cache"),
+    ("repro.runtime.trace", "Program spans (profiler tracing)"),
 ]
 
 HEADER = """# API reference
