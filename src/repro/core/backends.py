@@ -415,7 +415,8 @@ class SolveStats:
 
     Only :meth:`record` forces a sync: it reads each dispatched chunk's
     iteration counts back to the host, and it alone feeds ``lps``,
-    ``rounds``, ``simplex_iterations`` and ``lockstep_iterations``.  So a
+    ``rounds``, ``simplex_iterations``, ``lockstep_iterations`` and
+    ``phase_rewrites``.  So a
     solve with ``stats=`` waits for each chunk before dispatching the next.
     Every other counter is host bookkeeping the pipeline already holds,
     and ``host_syncs`` counts the syncs themselves, ``record``'s own
@@ -436,6 +437,12 @@ class SolveStats:
         ``max(iterations) * batch`` summed per dispatch: the lockstep cost
         model, in which every LP pays the slowest LP's iteration count.
         Compaction shrinks this toward ``simplex_iterations``.
+    phase_rewrites : int
+        Iterations in which a tile of the Pallas tableau kernel ran the
+        phase-I to phase-II objective rewrite, summed once per tile
+        (``LPSolution.phase_rewrites``): at most one per phase-I LP of
+        the tile.  Zero where every LP starts in phase II, and on the
+        backends that do not report it.
     warm_started : int
         LPs that entered a dispatch with a usable warm-start basis.
     resumed : int
@@ -512,6 +519,7 @@ class SolveStats:
     rounds: int = 0
     simplex_iterations: int = 0
     lockstep_iterations: int = 0
+    phase_rewrites: int = 0
     warm_started: int = 0
     resumed: int = 0
     spliced: int = 0
@@ -559,13 +567,21 @@ class SolveStats:
         sol : LPSolution
             The solution batch returned by a backend dispatch.
         """
-        iters = read_back(sol.iterations, "stats.record", self)
+        if sol.phase_rewrites is None:
+            iters = read_back(sol.iterations, "stats.record", self)
+            rewrites = 0
+        else:
+            # One read-back for both, so a round still makes one sync here.
+            iters, rewrites = read_back(
+                (sol.iterations, sol.phase_rewrites), "stats.record", self
+            )
         if iters.size == 0:
             return
         self.lps += int(iters.size)
         self.rounds += 1
         self.simplex_iterations += int(iters.sum())
         self.lockstep_iterations += int(iters.max()) * int(iters.size)
+        self.phase_rewrites += int(np.sum(rewrites))
 
 
 def read_back(x, site: str, stats: Optional[SolveStats] = None) -> np.ndarray:

@@ -147,6 +147,7 @@ def _trim_solution(sol: LPSolution, k: int) -> LPSolution:
         iterations=sol.iterations[:k],
         basis=None if sol.basis is None else sol.basis[:k],
         y=None if sol.y is None else sol.y[:k],
+        phase_rewrites=None if sol.phase_rewrites is None else sol.phase_rewrites[:k],
     )
 
 

@@ -356,6 +356,52 @@ def phase2_objective(
     return c_ext - priced
 
 
+def phase_status(
+    tab: jnp.ndarray,
+    phase: jnp.ndarray,
+    status: jnp.ndarray,
+    at_opt: jnp.ndarray,
+    feas_tol: jnp.ndarray,
+    spec: TableauSpec,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The optimum bookkeeping of :func:`phase_transition`, without the rewrite.
+
+    LPs at a phase-I optimum end INFEASIBLE unless ``-z0 <= feas_tol``;
+    the feasible ones (``to_phase2``) move to phase II.  LPs at a
+    phase-II optimum end OPTIMAL.  The feasibility test reads ``-z0``
+    from the objective row — never the artificial columns, which is why
+    the compact layout can drop them.  Every value is (B, 1, 1).
+    Returns the updated ``(phase, status, to_phase2)``.
+    """
+    m = spec.m
+    active = status == RUNNING
+    p1_done = active & at_opt & (phase == 1)
+    feasible = tab[:, m : m + 1, 0:1] <= feas_tol
+    to_phase2 = p1_done & feasible
+    status = jnp.where(p1_done & ~feasible, INFEASIBLE, status)
+    status = jnp.where(active & at_opt & (phase == 2), OPTIMAL, status)
+    phase = jnp.where(to_phase2, 2, phase)
+    return phase, status, to_phase2
+
+
+def phase2_row(
+    tab: jnp.ndarray,
+    basis: jnp.ndarray,
+    to_phase2: jnp.ndarray,
+    c_ext: jnp.ndarray,
+    spec: TableauSpec,
+    gather: bool = False,
+) -> jnp.ndarray:
+    """The objective row after the phase transition, (B, 1, Q).
+
+    :func:`phase2_objective` for the ``to_phase2`` LPs ((B, 1, 1)), the
+    current objective row for every other LP.
+    """
+    m = spec.m
+    new_obj = phase2_objective(tab, basis, spec, c_ext, gather)
+    return jnp.where(to_phase2, new_obj, tab[:, m : m + 1, :])
+
+
 def phase_transition(
     tab: jnp.ndarray,
     basis: jnp.ndarray,
@@ -369,29 +415,21 @@ def phase_transition(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Branch-free optimum bookkeeping: finish phase II, enter phase II.
 
-    For LPs at a phase-I optimum: feasible ones (``-z0 <= feas_tol``)
-    get their objective row rewritten in place via
-    :func:`phase2_objective` and continue into phase II (the paper does
-    this with a host round-trip between two kernel launches; here it is
-    a masked in-loop rewrite); infeasible ones terminate INFEASIBLE.
-    LPs at a phase-II optimum terminate OPTIMAL.  The feasibility test
-    reads ``-z0`` from the objective row — never the artificial columns,
-    which is why the compact layout can drop them.
+    For LPs at a phase-I optimum: feasible ones get their objective row
+    rewritten in place via :func:`phase2_objective` and continue into
+    phase II (the paper does this with a host round-trip between two
+    kernel launches; here it is a masked in-loop rewrite); infeasible
+    ones terminate INFEASIBLE.  LPs at a phase-II optimum terminate
+    OPTIMAL.  :func:`phase_status`, then :func:`phase2_row` written into
+    row ``m``, every iteration; the Pallas kernel calls the two itself,
+    to skip the rewrite where no LP of its tile needs it.
 
     ``phase``, ``status``, ``at_opt`` and ``feas_tol`` are (B, 1, 1).
     Returns the updated ``(tab, phase, status)``.
     """
-    m = spec.m
-    active = status == RUNNING
-    p1_done = active & at_opt & (phase == 1)
-    feasible = tab[:, m : m + 1, 0:1] <= feas_tol
-    to_phase2 = p1_done & feasible
-    status = jnp.where(p1_done & ~feasible, INFEASIBLE, status)
-    status = jnp.where(active & at_opt & (phase == 2), OPTIMAL, status)
-    new_obj = phase2_objective(tab, basis, spec, c_ext, gather)
-    rewrite = to_phase2 & (row_ids(tab.shape[1]) == m)  # (B, R, 1)
-    tab = jnp.where(rewrite, new_obj, tab)
-    phase = jnp.where(to_phase2, 2, phase)
+    phase, status, to_phase2 = phase_status(tab, phase, status, at_opt, feas_tol, spec)
+    row = phase2_row(tab, basis, to_phase2, c_ext, spec, gather)
+    tab = jnp.where(row_ids(tab.shape[1]) == spec.m, row, tab)
     return tab, phase, status
 
 

@@ -233,6 +233,13 @@ class LPSolution:
     is an approximate solution of ``min b.y  s.t.  A'y >= c, y >= 0``.
     The simplex backends leave it None (their duals live implicitly in
     the tableau's slack reduced costs).
+
+    ``phase_rewrites`` comes from the Pallas tableau kernel: the number
+    of iterations in which a tile ran the phase-I to phase-II objective
+    rewrite, on the tile's first row and 0 on its other rows, so a sum
+    over whole tiles counts each tile once.  Other backends leave it
+    None, and so does a solution rebuilt from several dispatches:
+    :attr:`~repro.core.backends.SolveStats.phase_rewrites` sums it.
     """
 
     objective: jnp.ndarray  # (B,)
@@ -241,6 +248,7 @@ class LPSolution:
     iterations: jnp.ndarray  # (B,) int32
     basis: Optional[jnp.ndarray] = None  # (B, m) int32 final basis
     y: Optional[jnp.ndarray] = None  # (B, m) dual point (first-order backends)
+    phase_rewrites: Optional[jnp.ndarray] = None  # (B,) int32 per-tile count
 
 
 def num_cols(m: int, n: int) -> int:

@@ -324,21 +324,25 @@ def _launch(
         vmem_limit_bytes=VMEM_BUDGET_BYTES,
         interpret=interpret,
     )
-    obj, x, status, iters, basis_out = outs[:5]
+    obj, x, status, iters, basis_out, rewrites = outs[:6]
     dtype = tab_p.dtype
     neg_inf = jnp.asarray(-jnp.inf, dtype)
     status = status[:bsz, 0]
     objective = jnp.where(status == 1, obj[:bsz, 0], neg_inf)
+    # Every row of a tile carries the tile's count; keep it on the tile's
+    # first row only, so a sum over rows counts each tile once.
+    first = jax.lax.broadcasted_iota(jnp.int32, rewrites.shape, 0) % tile_b == 0
     sol = LPSolution(
         objective=objective,
         x=x[:bsz, :n],
         status=status,
         iterations=iters[:bsz, 0],
         basis=basis_out[:bsz, :m],
+        phase_rewrites=jnp.where(first, rewrites, 0)[:bsz, 0],
     )
     if not want_state:
         return sol
-    tab_out, phase_out = outs[5:]
+    tab_out, phase_out = outs[6:]
     state = ResumeState(
         tab=tab_out[:bsz, : m + 1, : spec.q],
         basis=basis_out[:bsz, :m],

@@ -25,6 +25,24 @@ XLA lockstep path uses.  The engine is written in broadcasted-iota +
 one-hot form, which lowers to VPU-friendly selects under Mosaic, so the
 kernel and the XLA path agree bit-for-bit under deterministic rules.
 
+Gated phase transition: the kernel calls the engine's two halves of
+``phase_transition`` itself.  The status bookkeeping
+(``engine.phase_status``) runs every iteration on per-LP scalars.  The
+new objective row (``engine.phase2_row``: the basic-cost gather and a
+HIGHEST-precision pricing contraction) is computed under ``lax.cond``
+only in iterations where some LP of the tile leaves phase I; otherwise
+the cond hands back the current row.  The row goes back into the
+tableau through the 8-row sublane tile that holds it (``_put_row``), so
+no branch passes over the whole tableau (on a TPU v5e a cond that
+returns the whole tableau saved about half as much time per pivot at
+m = n = 100, and almost none at m = n = 200).  The skip is
+exact: with the gate closed no LP of the tile has ``to_phase2`` set, so
+``phase2_row`` would have returned the current row, and the kernel
+yields the same tableau, basis, status and iteration counts as the
+ungated XLA driver.  The gate stays closed through a single-phase solve
+and opens at most once per phase-I LP; the kernel counts its openings
+per tile (``LPSolution.phase_rewrites``).
+
 Compile-once dispatch: the iteration cap enters the kernel as a SCALAR
 INPUT (``cap_ref``, like ``feas_ref``), not a trace-time constant — the
 compaction scheduler's geometric round caps all run the one compiled
@@ -51,6 +69,20 @@ from ..core.tableau import TableauSpec
 _BIG = engine.BIG
 
 
+def _put_row(tab, i: int, row):
+    """``tab`` with row ``i`` (static) replaced by ``row``: (B, R, Q), (B, 1, Q).
+
+    Selects inside the 8-row sublane tile that holds row ``i`` alone and
+    splices it back at tile-aligned offsets, so the rest of the tableau
+    is not passed over.  ``R`` is a multiple of 8 (``kernels/ops.py``
+    pads the rows).
+    """
+    r0 = i - i % 8
+    tile = jnp.where(engine.row_ids(8) == i - r0, row, tab[:, r0 : r0 + 8, :])
+    parts = [p for p in (tab[:, :r0, :], tile, tab[:, r0 + 8 :, :]) if p.shape[1]]
+    return jnp.concatenate(parts, axis=1) if len(parts) > 1 else tile
+
+
 def _kernel(
     cap_ref,  # (2,) i32 SMEM — iteration cap (compile-once caps), first global row
     tab_ref,  # (TB, M1p, Qp) f32 VMEM — prebuilt tableau (padded)
@@ -63,6 +95,7 @@ def _kernel(
     status_ref,  # out (TB, 1) i32
     iters_ref,  # out (TB, 1) i32
     basis_out_ref,  # out (TB, Mp) i32 — final basis (warm-start reuse)
+    rewrites_ref,  # out (TB, 1) i32 — iterations in which the tile's gate opened
     *state_out_refs,  # want_state: out (TB, M1p, Qp) f32 tab, (TB, 1) i32 phase
     spec: TableauSpec,
     rule: str,
@@ -94,7 +127,7 @@ def _kernel(
     row0 = cap_ref[1] + pl.program_id(0) * tb
 
     def body(state):
-        tab, basis, phase, status, iters, step = state
+        tab, basis, phase, status, iters, rewrites, step = state
         active = status == RUNNING
 
         noise = (
@@ -107,10 +140,22 @@ def _kernel(
         )
         at_opt = max_c <= tol
 
-        tab, phase, status = engine.phase_transition(
-            tab, basis, phase, status, at_opt, c_ext, feas_tol, spec,
-            gather=False,  # Mosaic: one-hot reductions only
+        phase, status, to_phase2 = engine.phase_status(
+            tab, phase, status, at_opt, feas_tol, spec
         )
+        # The objective rewrite only where an LP of the tile needs it; a
+        # closed gate skips it, which is exact (see the module docstring).
+        gate = jnp.any(to_phase2)
+        row = jax.lax.cond(
+            gate,
+            lambda: engine.phase2_row(
+                tab, basis, to_phase2, c_ext, spec,
+                gather=False,  # Mosaic: one-hot reductions only
+            ),
+            lambda: tab[:, m : m + 1, :],
+        )
+        tab = _put_row(tab, m, row)
+        rewrites = rewrites + gate.astype(jnp.int32)
 
         pivoting = active & ~at_opt
         l, min_ratio, full_col = engine.ratio_test(tab, basis, e, spec, tol)
@@ -122,16 +167,17 @@ def _kernel(
             tab, basis, e, l, full_col, do_pivot, spec, tol, gather=False
         )
         iters = iters + do_pivot.astype(jnp.int32)
-        return tab, basis, phase, status, iters, step + 1
+        return tab, basis, phase, status, iters, rewrites, step + 1
 
     def cond(state):
-        _, _, _, status, _, step = state
+        _, _, _, status, _, _, step = state
         return jnp.logical_and(step < limit, jnp.any(status == RUNNING))
 
     status0 = jnp.full((tb, 1, 1), RUNNING, jnp.int32)
     iters0 = jnp.zeros((tb, 1, 1), jnp.int32)
-    tab, basis, phase, status, iters, _ = jax.lax.while_loop(
-        cond, body, (tab, basis, phase, status0, iters0, jnp.int32(0))
+    tab, basis, phase, status, iters, rewrites, _ = jax.lax.while_loop(
+        cond, body,
+        (tab, basis, phase, status0, iters0, jnp.int32(0), jnp.int32(0)),
     )
     status = jnp.where(status == RUNNING, ITER_LIMIT, status)
 
@@ -146,6 +192,7 @@ def _kernel(
     status_ref[...] = status.reshape(tb, 1)
     iters_ref[...] = iters.reshape(tb, 1)
     basis_out_ref[...] = engine.to_row(basis, mp, gather=False).reshape(tb, mp)
+    rewrites_ref[...] = jnp.full((tb, 1), rewrites, jnp.int32)
     if want_state:
         tab_out_ref, phase_out_ref = state_out_refs
         tab_out_ref[...] = tab
@@ -223,6 +270,7 @@ def simplex_pallas(
         per_lp,
         per_lp,
         pl.BlockSpec((tile_b, mp), lambda i: (i, 0)),
+        per_lp,
     ]
     out_shape = [
         jax.ShapeDtypeStruct((bsz, 1), tab.dtype),
@@ -230,6 +278,7 @@ def simplex_pallas(
         jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
         jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
         jax.ShapeDtypeStruct((bsz, mp), jnp.int32),
+        jax.ShapeDtypeStruct((bsz, 1), jnp.int32),
     ]
     if want_state:
         out_specs += [tab_block, per_lp]

@@ -219,3 +219,116 @@ def test_engine_solution_extraction_matches_manual(fixture_batch):
         np.testing.assert_allclose(
             float(np.asarray(b.c)[i] @ x[i]), float(sol.objective[i]), rtol=1e-9
         )
+
+
+# ---------------------------------------------------------------------------
+# The Pallas kernel's gated objective rewrite (kernels/simplex_pallas.py)
+# ---------------------------------------------------------------------------
+
+TILE = 8
+
+
+def _tiled_batch(dtype=np.float64) -> LPBatch:
+    """Three tiles of 8 (m=12, n=6): no phase-I LP; every LP in phase I;
+    a mixed tile whose two phase-I LPs leave phase I in different
+    iterations (one has a single lower bound, the other six)."""
+    m, n = 12, 6
+    rng = np.random.default_rng(7)
+    easy = lp.random_lp_batch(rng, 14, m, n, True, dtype=dtype)
+    hard = lp.random_lp_batch(rng, 10, m, n, False, dtype=dtype)
+
+    def rows(batch, lo, hi):
+        return [np.asarray(v)[lo:hi] for v in (batch.a, batch.b, batch.c)]
+
+    parts = [rows(easy, 0, 8), rows(hard, 0, 8), rows(easy, 8, 11),
+             rows(hard, 8, 10), rows(easy, 11, 14)]
+    a, b, c = (np.concatenate(p) for p in zip(*parts))
+    b[19, n + 1 : 2 * n] = 1.0  # drop five of LP 19's six lower bounds
+    return LPBatch(a, b, c)
+
+
+def _phase1_per_tile(b) -> np.ndarray:
+    return (np.asarray(b) < 0).any(axis=1).reshape(-1, TILE).sum(axis=1)
+
+
+def _assert_same(xla, pal):
+    for field in ("status", "iterations", "basis", "objective", "x"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(xla, field)), np.asarray(getattr(pal, field)),
+            err_msg=field,
+        )
+
+
+def test_gated_rewrite_matches_xla_driver_bitwise_per_kind_of_tile():
+    from repro.kernels import ops
+
+    batch = _tiled_batch()
+    np.testing.assert_array_equal(_phase1_per_tile(batch.b), [0, 8, 2])
+    xla = simplex.solve_batched(batch.a, batch.b, batch.c)
+    pal = ops.simplex_solve(batch.a, batch.b, batch.c, tile_b=TILE)
+    _assert_same(xla, pal)
+    assert (np.asarray(xla.status) == lp.OPTIMAL).all()
+    # The tile's count rides on its first row.  The mixed tile's gate
+    # opened twice: its two phase-I LPs crossed in different iterations.
+    rewrites = np.asarray(pal.phase_rewrites).reshape(-1, TILE)
+    np.testing.assert_array_equal(rewrites[:, 1:], 0)
+    np.testing.assert_array_equal(rewrites[:, 0], [0, 1, 2])
+
+
+def test_gated_rewrite_resumes_across_the_phase_transition():
+    """A capped first round that stops before any LP leaves phase I, and a
+    resumed round that crosses it, equal the uninterrupted XLA solve."""
+    from repro.kernels import ops
+
+    batch = _tiled_batch()
+    xla = simplex.solve_batched(batch.a, batch.b, batch.c)
+    first, state = ops.simplex_solve(
+        batch.a, batch.b, batch.c, tile_b=TILE, max_iters=1, want_state=True
+    )
+    assert (np.asarray(first.phase_rewrites) == 0).all()
+    np.testing.assert_array_equal(
+        (np.asarray(state.phase) == 1).reshape(-1, TILE).sum(axis=1), [0, 8, 2]
+    )
+    rest = lp.auto_cap(12, 6) - 1
+    second, _ = ops.simplex_resume(
+        batch.b, batch.c, state, tile_b=TILE, max_iters=rest
+    )
+    np.testing.assert_array_equal(np.asarray(second.phase_rewrites)[::TILE], [0, 1, 2])
+    np.testing.assert_array_equal(
+        np.asarray(first.iterations) + np.asarray(second.iterations),
+        np.asarray(xla.iterations),
+    )
+    for field in ("status", "basis", "objective", "x"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(xla, field)), np.asarray(getattr(second, field)),
+            err_msg=field,
+        )
+
+
+@pytest.mark.parametrize("two_phase", [False, True])
+def test_phase_rewrites_counter(two_phase):
+    """``SolveStats.phase_rewrites``: 0 where every LP starts in phase II,
+    else between 1 and the tile's phase-I LPs per tile; read in the same
+    host sync as the iteration counts."""
+    from repro.kernels import ops
+
+    batch = lp.random_lp_batch(
+        np.random.default_rng(21), 4 * TILE, 12, 6, not two_phase, dtype=np.float64
+    )
+    pal = ops.simplex_solve(batch.a, batch.b, batch.c, tile_b=TILE)
+    per_tile = np.asarray(pal.phase_rewrites)[::TILE]
+    phase1 = _phase1_per_tile(batch.b)
+    if two_phase:
+        assert (phase1 > 0).all()
+        assert ((per_tile >= 1) & (per_tile <= phase1)).all(), (per_tile, phase1)
+    else:
+        assert (per_tile == 0).all()
+
+    counted = {}
+    for backend in ("xla", "pallas"):
+        stats = repro.SolveStats()
+        repro.solve(batch, SolveOptions(backend=backend, tile_b=TILE), stats=stats)
+        counted[backend] = stats
+    assert counted["pallas"].phase_rewrites == int(per_tile.sum())
+    assert counted["xla"].phase_rewrites == 0
+    assert counted["pallas"].host_syncs == counted["xla"].host_syncs == counted["pallas"].rounds

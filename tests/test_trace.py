@@ -19,6 +19,7 @@ import pytest
 
 import repro
 from repro import SolveOptions, SolveStats
+from repro.core.lp import random_lp_batch
 from repro.runtime import trace
 from repro.serve.engine import LPEngine
 from repro.serve.loadgen import lp_request_mix
@@ -163,6 +164,21 @@ def test_counters_are_kept_without_a_profiler(warm):
     _solve(_batch(), warm, stats=stats)
     assert stats.host_syncs == 3
     assert stats.bytes_staged == B * 4 * (M * N + M + N)
+
+
+def test_stats_reads_phase_rewrites_in_the_chunk_sync(tmp_path):
+    """The Pallas kernel's ``phase_rewrites`` come back with the iteration
+    counts: still one ``stats.record`` sync per chunk."""
+    opts = SolveOptions(backend="pallas", chunk_size=CHUNK)
+    made = random_lp_batch(np.random.default_rng(5), B, 2 * N, N, feasible_start=False)
+    batch = repro.LPBatch(*(np.asarray(v) for v in (made.a, made.b, made.c)))
+    _solve(batch, opts, stats=SolveStats())  # warm
+    stats = SolveStats()
+    _, spans, _ = _traced(lambda: _solve(batch, opts, stats=stats), tmp_path)
+    syncs = [s for s in spans if s.name == "dispatch.sync"]
+    assert stats.host_syncs == len(syncs) == 3 == stats.rounds
+    assert {s.attrs["site"] for s in syncs} == {"stats.record"}
+    assert 3 <= stats.phase_rewrites <= B
 
 
 def test_compaction_adds_one_status_sync_per_round_after_the_first(tmp_path):
