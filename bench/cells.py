@@ -5,14 +5,17 @@ mix.  Everything else is found from those names:
 
 - ``configs[].file``: the configuration's sizes, generator, solver
   options and correctness limits (JSON);
+- ``bench/inputs/<generator>.py``: the LP class that the configuration's
+  ``generator`` key names, which makes the inputs from the seed
+  (``bench/lpgen.py``);
 - ``bench/traffic/<traffic>.json``: the traffic mix, whose ``loop`` key
   names the general driver in ``bench/loops/`` that reads it;
 - ``bench/metrics/<metric>.py``: one reader per per-layer metric, for
   every per-layer metric whose ``workloads`` list names the cell (or that
   moves an end-to-end metric the cell reports, where it has no list).
 
-So a later change adds a cell, a mix or a metric by adding files and
-entries, and edits no file that is already there.
+So a later change adds a cell, a mix, an LP class or a metric by adding
+files and entries, and edits no file that is already there.
 """
 
 from __future__ import annotations
@@ -22,6 +25,8 @@ import importlib.util
 import json
 from pathlib import Path
 from typing import Callable, Dict, List
+
+from bench import lpgen
 
 
 @dataclasses.dataclass
@@ -35,6 +40,7 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     readers: Dict[str, Callable]
+    inputs: lpgen.LPClass
 
 
 def _json(path: Path) -> dict:
@@ -77,4 +83,5 @@ def load(root: Path, name: str) -> Cell:
     reported = {m["name"] for m in e2e}
     layer = [m for m in bench["per_layer"] if _applies(m, name, reported)]
     readers = {m["name"]: _reader(root / "bench" / "metrics" / f"{m['name']}.py") for m in layer}
-    return Cell(name, int(w["chips"]), config, traffic, e2e, layer, readers)
+    inputs = lpgen.load(root, config["generator"])
+    return Cell(name, int(w["chips"]), config, traffic, e2e, layer, readers, inputs)

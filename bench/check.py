@@ -20,7 +20,9 @@ in the configuration's ``limits``:
 
 The sample is drawn from the seed, and holds besides the row of each
 block that took the most pivots.  ``primal_resid`` is computed in float64
-from the inputs on every row.
+from the inputs on every row.  A block whose ``a`` is one ``(m, n)``
+matrix shares it over its rows: it is read as that matrix for every row,
+and never copied per row.
 """
 
 from __future__ import annotations
@@ -49,17 +51,26 @@ def sample_rows(blocks, seed: int, size: int) -> List[tuple]:
     return sorted(picks)
 
 
+def _a(block, rows):
+    """``A`` of ``rows``: the block's shared ``(m, n)`` matrix, or its rows' own."""
+    return block.a if block.a.ndim == 2 else block.a[rows]
+
+
 def _primal_resid(block) -> float:
     """``primal_resid`` over the block's optimal rows."""
     resid = 0.0
     opt = np.nonzero(block.status == 1)[0]
     for lo in range(0, opt.size, _BLOCK_ROWS):
         rows = opt[lo : lo + _BLOCK_ROWS]
-        a = block.a[rows].astype(np.float64)
+        a = _a(block, rows).astype(np.float64)
         b = block.b[rows].astype(np.float64)
         x = block.x[rows].astype(np.float64)
-        ax = np.matmul(a, x[:, :, None])[:, :, 0]
-        size = 1.0 + np.matmul(np.abs(a), np.abs(x)[:, :, None])[:, :, 0] + np.abs(b)
+        if a.ndim == 2:
+            ax = x @ a.T
+            size = 1.0 + np.abs(x) @ np.abs(a).T + np.abs(b)
+        else:
+            ax = np.matmul(a, x[:, :, None])[:, :, 0]
+            size = 1.0 + np.matmul(np.abs(a), np.abs(x)[:, :, None])[:, :, 0] + np.abs(b)
         rows_viol = np.max((ax - b) / size, axis=1)
         sign_viol = np.max(-x, axis=1) / (1.0 + np.max(np.abs(x), axis=1))
         r = np.max(np.maximum(np.maximum(rows_viol, sign_viol), 0.0))
@@ -73,7 +84,7 @@ def numbers_against(blocks, picks, ref) -> Dict[str, float]:
     out = {"unanswered": 0, "status_mismatch": 0, "objective_rel_err": 0.0,
            "primal_resid": 0.0}
     for b in blocks:
-        n = len(b.a)
+        n = len(b.b)
         answered = len(b.status) == n
         out["unanswered"] += n if not answered else int((~np.isin(b.status, FINAL)).sum())
         if answered:
@@ -92,7 +103,7 @@ def numbers_against(blocks, picks, ref) -> Dict[str, float]:
 
 
 def reference_answers(blocks, picks, precision: str = "float64"):
-    a = np.stack([blocks[k].a[r] for k, r in picks])
+    a = np.stack([_a(blocks[k], r) for k, r in picks])
     b = np.stack([blocks[k].b[r] for k, r in picks])
     c = np.stack([blocks[k].c[r] for k, r in picks])
     return reference.solve(a, b, c, precision)
@@ -115,7 +126,7 @@ def control_blocks(blocks, picks, precision: str = "bfloat16"):
     out = []
     for i, (k, r) in enumerate(picks):
         src = blocks[k]
-        out.append(type(src)(src.a[r : r + 1], src.b[r : r + 1], src.c[r : r + 1],
+        out.append(type(src)(_a(src, slice(r, r + 1)), src.b[r : r + 1], src.c[r : r + 1],
                              status[i : i + 1], objective[i : i + 1].astype(np.float32),
                              x[i : i + 1].astype(np.float32), np.zeros(1, np.int32)))
     return out, [(i, 0) for i in range(len(picks))]

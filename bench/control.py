@@ -25,15 +25,15 @@ ROOT = Path(__file__).resolve().parents[1]
 
 def readings(cell, seed: int, seconds: float, precision: str = "bfloat16") -> dict:
     """The control's numbers for one seed of a run of ``seconds``."""
-    from bench import check, lpgen
+    from bench import check
     from bench.loops import Block
 
     cfg = cell.config
     if cell.traffic["loop"] == "open":
         count = int(round(float(cell.traffic["rate"]) * seconds))
-        a, b, c = lpgen.host_batch(cfg["generator"], seed, 0, count, cfg["m"], cfg["n"])
     else:
-        a, b, c = lpgen.host_batch(cfg["generator"], seed, 0, cfg["batch"], cfg["m"], cfg["n"])
+        count = cfg["batch"]
+    a, b, c = cell.inputs.draw(cfg, seed, 0, count)
     rows = len(b)
     empty = Block(a, b, c, np.ones(rows, np.int32), np.zeros(rows, np.float32),
                   np.zeros(c.shape, np.float32), np.zeros(rows, np.int32))

@@ -26,7 +26,8 @@ class Block:
     """Inputs and returned rows of one group of answers (one call, or a serve run).
 
     ``a``, ``b``, ``c`` are the canonical LP data as the harness made it
-    (host NumPy).  ``status``, ``objective``, ``x`` and ``iterations`` are
+    (host NumPy); ``a`` is ``(rows, m, n)``, or one ``(m, n)`` matrix that
+    every row shares.  ``status``, ``objective``, ``x`` and ``iterations`` are
     what the program returned for those rows, in the same order.  A row
     the program never returned carries status 0.
     """

@@ -9,7 +9,9 @@ Traffic keys:
   cell's work per chunk; the batch dimension is sharded over a
   ``Mesh(devices, ("data",))``.
 
-A call hands ``repro.solve`` an ``LPBatch`` of NumPy arrays, as an
+A call hands ``repro.solve`` the NumPy arrays of a pool batch in the
+container the configuration's LP class makes of them (``LPBatch`` by
+default, ``SharedLPBatch`` for a class whose LPs share one ``A``), as an
 application does, and ends when status, objective, ``x`` and iteration
 counts are back on the host.  The window runs whole calls until
 ``seconds`` have passed; ``lps_per_s`` is the LPs that reached a final
@@ -24,7 +26,6 @@ from typing import List
 
 import numpy as np
 
-from bench import lpgen
 from bench.loops import Block, Record
 from bench.loops.spans import Spans
 
@@ -34,6 +35,7 @@ FINAL = (1, 2, 3)  # optimal, unbounded, infeasible
 @dataclasses.dataclass
 class State:
     repro: object
+    inputs: object
     options: object
     mesh: object
     pool: List[tuple]
@@ -62,23 +64,26 @@ def setup(cell, seed, seconds, devices) -> State:
     # than one block's draw at a time.
     pool = []
     for i in range(int(traffic["pool"])):
-        parts = [lpgen.host_batch(cfg["generator"], seed, i * chips + k, cfg["batch"], m, n)
+        parts = [cell.inputs.draw(cfg, seed, i * chips + k, cfg["batch"])
                  for k in range(chips)]
         pool.append(tuple(np.concatenate(p) if chips > 1 else p[0] for p in zip(*parts)))
-    routed = dispatch.resolve_backend(m, n, jnp.float32, options, batch=pool[0][1].shape[0])
+    shared = isinstance(cell.inputs.problem(repro, *pool[0]), repro.SharedLPBatch)
+    routed = dispatch.resolve_backend(m, n, jnp.float32, options, shared=shared,
+                                      batch=pool[0][1].shape[0])
     notes = [f"LPs per call {pool[0][1].shape[0]}, m={m}, n={n}, chunk_size "
              f"{options.chunk_size}, chips {chips}; {options.backend!r} routes to "
              f"{routed.backend!r} (layout {routed.layout}, tile_b {routed.tile_b})"]
-    state = State(repro, options, mesh, pool, m, n, notes)
+    state = State(repro, cell.inputs, options, mesh, pool, m, n, notes)
     _call(state, 0, Spans())  # warm-up: the window's only shape
     state.calls = 1  # the window starts on the next batch of the pool
     return state
 
 
 def _call(state: State, i: int, spans: Spans):
-    a, b, c = state.pool[i % len(state.pool)]
+    batch = state.pool[i % len(state.pool)]
     with spans("solve"):
-        sol = state.repro.solve(state.repro.LPBatch(a, b, c), state.options, mesh=state.mesh)
+        sol = state.repro.solve(state.inputs.problem(state.repro, *batch), state.options,
+                                mesh=state.mesh)
     with spans("result"):
         out = (np.asarray(sol.status), np.asarray(sol.objective), np.asarray(sol.x),
                np.asarray(sol.iterations))
@@ -116,10 +121,9 @@ def after_trace(state: State, record: Record) -> dict:
     puts a host sync between chunks, so it stays out of the window.
     """
     stats = state.repro.SolveStats()
-    for i in range(len(state.pool)):
-        a, b, c = state.pool[i]
-        sol = state.repro.solve(state.repro.LPBatch(a, b, c), state.options, mesh=state.mesh,
-                                stats=stats)
+    for batch in state.pool:
+        sol = state.repro.solve(state.inputs.problem(state.repro, *batch), state.options,
+                                mesh=state.mesh, stats=stats)
         np.asarray(sol.status)
     return {"stats": stats, "m": state.m, "n": state.n}
 

@@ -13,9 +13,11 @@ Traffic keys:
   stepping to finish the window's requests;
 - ``trace_seconds``: the window of a traced run.
 
-The window holds ``round(rate * seconds)`` requests; their gaps are
-exponential draws from the seed, scaled so the last arrives just before
-the window ends, so every seed offers the same work.  The loop is the
+The requests are the ``requests`` that the configuration's LP class
+makes of its draw (``bench/lpgen.py``).  The window holds
+``round(rate * seconds)`` of them; their gaps are exponential draws from
+the seed, scaled so the last arrives just before the window ends, so
+every seed offers the same work.  The loop is the
 continuous mode of ``LPEngine``: it submits each request when it is due,
 calls ``step()`` while work is pending or in flight, and sleeps until the
 next arrival when there is none.  A request's latency runs from its
@@ -33,7 +35,6 @@ from typing import List
 
 import numpy as np
 
-from bench import lpgen
 from bench.loops import Block, Record, percentile
 from bench.loops.spans import Spans
 
@@ -56,10 +57,9 @@ def arrival_times(seed: int, count: int, seconds: float) -> np.ndarray:
     return times[:-1] / times[-1] * seconds
 
 
-def _problems(repro, cfg, seed, salt, count):
-    a, b, c = lpgen.host_batch(cfg["generator"], seed, salt, count, cfg["m"], cfg["n"])
-    probs = [repro.LPProblem.make(c[i], a[i], bu=b[i], maximize=True) for i in range(count)]
-    return probs, (a, b, c)
+def _problems(repro, cell, seed, salt, count):
+    a, b, c = cell.inputs.draw(cell.config, seed, salt, count)
+    return cell.inputs.requests(repro, a, b, c), (a, b, c)
 
 
 def _replay(engine, problems, arrivals, limit, spans: Spans):
@@ -103,7 +103,7 @@ def setup(cell, seed, seconds, devices) -> State:
     cfg, traffic = cell.config, cell.traffic
     rate = float(traffic["rate"])
     count = int(round(rate * seconds))
-    problems, data = _problems(repro, cfg, seed, 0, count)
+    problems, data = _problems(repro, cell, seed, 0, count)
     arrivals = arrival_times(seed, count, seconds)
     options = repro.SolveOptions(**cfg["options"])
     engine = LPEngine(options, flush_every=1 << 30)
@@ -111,7 +111,7 @@ def setup(cell, seed, seconds, devices) -> State:
     # the cell's rate on requests of their own.
     bursts = list(range(1, int(traffic["warm_bursts"]) + 1))
     warm_count = int(round(rate * float(traffic["warm_seconds"])))
-    warm, _ = _problems(repro, cfg, seed, 1, max(warm_count, sum(bursts)))
+    warm, _ = _problems(repro, cell, seed, 1, max(warm_count, sum(bursts)))
     spans = Spans()
     used = 0
     for size in bursts:
@@ -163,7 +163,10 @@ def after_trace(state: State, record: Record) -> dict:
 def answers(state: State, record: Record) -> List[Block]:
     tickets = record.data["tickets"]
     count = len(tickets)
-    a, b, c = (v[:count] for v in state.data)
+    a, b, c = state.data
+    if a.ndim == 3:  # one A per LP; a 2-D ``a`` is shared by every row
+        a = a[:count]
+    b, c = b[:count], c[:count]
     status = np.zeros(count, np.int32)
     objective = np.full(count, np.nan, np.float32)
     x = np.zeros((count, c.shape[1]), np.float32)

@@ -1,22 +1,36 @@
-"""Seeded LP batches of the paper's two experiment classes, made on the device.
+"""Resolve an LP class by name: ``bench/inputs/<generator>.py``, one module per class.
 
-Copied from ``chip_smoke.py`` (``_constraints``, ``feasible_batch``,
-``two_phase_batch``) so that a later change to the program cannot change
-the benchmark's inputs.  Each batch is canonical: maximise ``c.x`` subject
-to ``A x <= b`` and ``x >= 0``, in float32.
+A configuration's ``generator`` key names its class.  The module offers:
 
-``host_batch`` draws a batch in one jitted call on the default device and
-copies it to host memory as NumPy, which is how an application hands
-``repro.solve`` its LPs.
+- ``draw(cfg, seed, index, rows)``: batch ``index`` of a run's inputs,
+  ``rows`` LPs at the configuration's sizes, as host NumPy ``(a, b, c)``
+  made from the seed (in one jitted call on the default device, then
+  copied to host memory, which is how an application hands ``repro.solve``
+  its LPs).  Each LP is canonical: maximise ``c.x`` subject to
+  ``A x <= b`` and ``x >= 0``.  ``a`` is ``(rows, m, n)``, or one
+  ``(m, n)`` matrix for a class whose LPs share it;
+- optionally ``problem(repro, a, b, c)``: what a closed-loop call hands
+  ``repro.solve``; by default ``repro.LPBatch(a, b, c)``;
+- optionally ``requests(repro, a, b, c)``: the open loop's ``LPProblem``
+  requests, one per row; by default ``LPProblem.make(c[i], a[i],
+  bu=b[i], maximize=True)``.
+
+So a later change adds a class by adding a module, and edits no file that
+is already there.
 """
 
 from __future__ import annotations
 
-import functools
+import dataclasses
+import importlib.util
+from pathlib import Path
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def key(seed: int, *salt: int):
@@ -25,46 +39,40 @@ def key(seed: int, *salt: int):
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32))
 
 
-def _constraints(k, bsz, m, n):
+def constraints(k, bsz, m, n):
     """U(-1, 1) rows with a strengthened diagonal."""
     a = jax.random.uniform(k, (bsz, m, n), jnp.float32, -1.0, 1.0)
     diag = jnp.eye(m, n, dtype=bool)
     return jnp.where(diag, jnp.abs(a) + 1.0, a)
 
 
-def feasible(k, bsz, m, n):
-    """Fig. 8 class: b > 0, so the origin is a feasible start."""
-    ka, kb, kc = jax.random.split(k, 3)
-    a = _constraints(ka, bsz, m, n)
-    b = jax.random.uniform(kb, (bsz, m), jnp.float32, 1.0, 10.0)
-    c = jax.random.uniform(kc, (bsz, n), jnp.float32, 0.1, 1.0)
-    return a, b, c
+def _batch(repro, a, b, c):
+    return repro.LPBatch(a, b, c)
 
 
-def two_phase(k, bsz, m, n):
-    """Fig. 9 class: feasible at a random interior x0, but many b_i < 0.
-
-    ``b = A x0 + slack`` for ``x0`` in [0.5, 1.5]: rows whose ``A x0`` is
-    negative give ``b_i < 0``, so the origin is infeasible and phase I runs.
-    """
-    ka, kx, ks, kc = jax.random.split(k, 4)
-    a = _constraints(ka, bsz, m, n)
-    x0 = jax.random.uniform(kx, (bsz, n), jnp.float32, 0.5, 1.5)
-    slack = jax.random.uniform(ks, (bsz, m), jnp.float32, 0.1, 1.0)
-    b = jnp.einsum("bmn,bn->bm", a, x0, precision=jax.lax.Precision.HIGHEST) + slack
-    c = jax.random.uniform(kc, (bsz, n), jnp.float32, 0.1, 1.0)
-    return a, b, c
+def _rows(repro, a, b, c):
+    return [repro.LPProblem.make(c[i], a[i], bu=b[i], maximize=True) for i in range(len(b))]
 
 
-GENERATORS = {"feasible": feasible, "two_phase": two_phase}
+@dataclasses.dataclass(frozen=True)
+class LPClass:
+    """One LP class's module, with the defaults filled in."""
+
+    draw: Callable
+    problem: Callable
+    requests: Callable
 
 
-@functools.partial(jax.jit, static_argnames=("generator", "bsz", "m", "n"))
-def _draw(k, *, generator, bsz, m, n):
-    return GENERATORS[generator](k, bsz, m, n)
+def load(root: Path, generator: str) -> LPClass:
+    """The class ``bench/inputs/<generator>.py`` under ``root``; raises ``FileNotFoundError``."""
+    path = Path(root) / "bench" / "inputs" / f"{generator}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_inputs_{generator}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return LPClass(module.draw, getattr(module, "problem", _batch),
+                   getattr(module, "requests", _rows))
 
 
 def host_batch(generator: str, seed: int, index: int, bsz: int, m: int, n: int):
     """Batch ``index`` of a run's pool as host NumPy ``(a, b, c)``."""
-    out = _draw(key(seed, index), generator=generator, bsz=bsz, m=m, n=n)
-    return tuple(np.asarray(v) for v in out)
+    return load(ROOT, generator).draw({"m": m, "n": n}, seed, index, bsz)

@@ -4,6 +4,11 @@ The copy keeps every file and entry of the benchmark and shrinks only
 the sizes (m, n, LPs per call, chunk, sample, serve rate and warm-up),
 so the harness's own functions run end to end in seconds.  The chip
 check of ``bench/run.py:main`` is the one part they skip.
+
+A configuration may carry its own tiny sizes: ``"tiny": {"cpu": {...},
+"control": {...}}``, the keys that the copy sets on the CPU, and the
+``m`` and ``n`` at which the control test reads the bfloat16 control.
+Without it the copy takes ``TINY_CONFIG`` and the control ``TINY_CONTROL``.
 """
 
 from __future__ import annotations
@@ -11,6 +16,7 @@ from __future__ import annotations
 import json
 import shutil
 import sys
+import time
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
@@ -19,6 +25,7 @@ for p in (str(REPO), str(REPO / "src")):
         sys.path.insert(0, p)
 
 TINY_CONFIG = {"m": 6, "n": 5, "batch": 64, "check_sample": 16}
+TINY_CONTROL = {"m": 40, "n": 40}  # at m=n=6 bfloat16 rounding stays under the limits
 TINY_CHUNK = 32
 TINY_OPEN = {"rate": 20, "warm_seconds": 1, "warm_bursts": 4, "drain_seconds": 60}
 
@@ -43,15 +50,23 @@ SERVED_LAYER = [
          "latency_p95_ms"))]
 
 
-def make_tiny(dest: Path) -> Path:
-    """Copy the benchmark to ``dest`` at tiny sizes, plus the served cell; returns the root."""
-    shutil.copytree(REPO / "bench", dest / "bench",
+def control_sizes(cfg: dict) -> dict:
+    """The ``m`` and ``n`` at which the control test reads a configuration's control."""
+    return cfg.get("tiny", {}).get("control", TINY_CONTROL)
+
+
+def make_tiny(dest: Path, source: Path = REPO) -> Path:
+    """Copy the benchmark at ``source`` to ``dest`` at tiny sizes, plus the served cell.
+
+    Returns the copy's root.
+    """
+    shutil.copytree(source / "bench", dest / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    bench = json.loads((source / "BENCHMARK.json").read_text())
     for c in bench["configs"]:
         path = dest / c["file"]
         cfg = json.loads(path.read_text())
-        cfg.update(TINY_CONFIG)
+        cfg.update(cfg.get("tiny", {}).get("cpu", TINY_CONFIG))
         if cfg["options"].get("chunk_size"):
             cfg["options"]["chunk_size"] = TINY_CHUNK
         path.write_text(json.dumps(cfg))
@@ -65,3 +80,50 @@ def make_tiny(dest: Path) -> Path:
     bench["per_layer"] += SERVED_LAYER
     (dest / "BENCHMARK.json").write_text(json.dumps(bench))
     return dest
+
+
+def run(root: Path, workload: str, seed: int = 2**33 + 17, seconds: float = 1.0) -> dict:
+    """One run of ``workload`` in the tiny copy at ``root``, on the CPU; its result."""
+    import jax
+
+    from bench import run as bench_run
+
+    return bench_run.run_cell(root, workload, seed, seconds, False, jax.devices(),
+                              time.perf_counter())
+
+
+def broken_round(kind: str):
+    """A ``dispatch_round`` that breaks the round's answers the way ``kind`` says.
+
+    ``unchanged``: the round returns its state unchanged, with made-up
+    optimal rows; ``half``: the second half of the batch left out;
+    ``altered``: the first row's answer altered where it is produced.
+    """
+    import jax.numpy as jnp
+
+    from repro.core import dispatch
+
+    original = dispatch.dispatch_round
+
+    def broken(batch, options, *args, **kwargs):
+        sol, state = original(batch, options, *args, **kwargs)
+        rows = sol.status.shape[0]
+        if kind == "unchanged":
+            sol = sol.__class__(objective=jnp.zeros_like(sol.objective),
+                                x=jnp.zeros_like(sol.x),
+                                status=jnp.ones_like(sol.status),
+                                iterations=jnp.zeros_like(sol.iterations), basis=sol.basis)
+            state = kwargs.get("state")
+        elif kind == "half":
+            keep = jnp.arange(rows) < (rows + 1) // 2
+            sol = sol.__class__(objective=jnp.where(keep, sol.objective, -jnp.inf),
+                                x=jnp.where(keep[:, None], sol.x, 0.0),
+                                status=jnp.where(keep, sol.status, 0),
+                                iterations=sol.iterations, basis=sol.basis)
+        elif kind == "altered":
+            sol = sol.__class__(objective=sol.objective.at[0].multiply(1.1),
+                                x=sol.x.at[0].multiply(1.1), status=sol.status,
+                                iterations=sol.iterations, basis=sol.basis)
+        return sol, state
+
+    return broken

@@ -82,6 +82,12 @@ def test_unknown_workload_and_missing_files_raise(tmp_path):
     (tmp_path / "bench").symlink_to(REPO / "bench")
     with pytest.raises(FileNotFoundError):
         cells.load(tmp_path, bench["workloads"][0]["name"])
+    cfg = json.loads((REPO / BENCH["configs"][0]["file"]).read_text())
+    (tmp_path / "no_class.json").write_text(json.dumps(dict(cfg, generator="no_such_class")))
+    bench = dict(BENCH, configs=[dict(BENCH["configs"][0], file="no_class.json")])
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(FileNotFoundError, match="no_such_class"):
+        cells.load(tmp_path, bench["workloads"][0]["name"])
 
 
 def test_a_cell_added_as_data_files_alone_is_picked_up(tiny):

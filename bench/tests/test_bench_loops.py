@@ -11,10 +11,9 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-import jax.numpy as jnp
 import pytest
 
-from bench_tiny import SERVED_CELL, SERVED_METRICS
+from bench_tiny import SERVED_CELL, SERVED_METRICS, broken_round, control_sizes
 
 REPO = Path(__file__).resolve().parents[2]
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -43,40 +42,10 @@ def test_the_bfloat16_control_fails_the_comparison(tiny, workload):
     from bench import cells, control
 
     cell = cells.load(tiny.root, workload)
-    cell.config.update(m=40, n=40)  # at m=n=6 bfloat16 rounding stays under the limits
+    cell.config.update(control_sizes(cell.config))
     for seed in (2**33 + 3, 5):
         assert control.readings(cell, seed, 1.0)["fails"], seed
         assert not control.readings(cell, seed, 1.0, precision="float64")["fails"], seed
-
-
-def _broken(kind):
-    """A ``dispatch_round`` that breaks the round's answers the way ``kind`` says."""
-    from repro.core import dispatch
-
-    original = dispatch.dispatch_round
-
-    def broken(batch, options, *args, **kwargs):
-        sol, state = original(batch, options, *args, **kwargs)
-        rows = sol.status.shape[0]
-        if kind == "unchanged":
-            sol = sol.__class__(objective=jnp.zeros_like(sol.objective),
-                                x=jnp.zeros_like(sol.x),
-                                status=jnp.ones_like(sol.status),
-                                iterations=jnp.zeros_like(sol.iterations), basis=sol.basis)
-            state = kwargs.get("state")
-        elif kind == "half":
-            keep = jnp.arange(rows) < (rows + 1) // 2
-            sol = sol.__class__(objective=jnp.where(keep, sol.objective, -jnp.inf),
-                                x=jnp.where(keep[:, None], sol.x, 0.0),
-                                status=jnp.where(keep, sol.status, 0),
-                                iterations=sol.iterations, basis=sol.basis)
-        elif kind == "altered":
-            sol = sol.__class__(objective=sol.objective.at[0].multiply(1.1),
-                                x=sol.x.at[0].multiply(1.1), status=sol.status,
-                                iterations=sol.iterations, basis=sol.basis)
-        return sol, state
-
-    return broken
 
 
 @pytest.mark.parametrize("kind", ["unchanged", "half", "altered"])
@@ -84,6 +53,6 @@ def _broken(kind):
 def test_a_broken_timed_path_is_not_correct(tiny, monkeypatch, workload, kind):
     from repro.core import dispatch
 
-    monkeypatch.setattr(dispatch, "dispatch_round", _broken(kind))
+    monkeypatch.setattr(dispatch, "dispatch_round", broken_round(kind))
     res = tiny.run(workload)
     assert not res["correct"], (kind, res["checked"])
